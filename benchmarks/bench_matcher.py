@@ -59,7 +59,7 @@ def _assert_paths_match_oracle(q, db, valid, metric):
     o = [np.asarray(x) for x in ref.match_best2(q, db, valid, metric=metric)]
     for path in dispatch.MATCH_PATHS:
         got = [np.asarray(x) for x in ops.match_best2(
-            q, db, valid, metric=metric, path=path, interpret=True)]
+            q, db, valid, metric=metric, path=path)]
         if metric == "hamming":   # integer distances: bit-identical
             ok = all(np.array_equal(a, b) for a, b in zip(got, o))
         else:

@@ -2,7 +2,7 @@
 extraction over tile bundles (map/shuffle/reduce on a TPU mesh)."""
 from repro.core.bundle import TileBundle, BundleStore, tile_scene, bundle_scenes  # noqa: F401
 from repro.core.engine import (  # noqa: F401
-    extract_features, extract_features_multi, make_distributed_extractor,
+    extract_features, extract_features_multi,
     ALGORITHMS,
 )
 from repro.core.job import DifetJob, JobManifest, ManifestJob  # noqa: F401

@@ -2,8 +2,8 @@
 ORB (steered BRIEF, 256-bit).
 
 Descriptors are computed at capacity-K keypoints per tile with static
-shapes: patch extraction is a vmapped ``dynamic_slice`` (clipped at tile
-borders), histogramming is dense one-hot einsums (MXU-friendly — see
+shapes: patch extraction is one batched gather (clipped at tile
+borders), histogramming is a fixed-order one-hot accumulation (see
 DESIGN.md §5 for why these are not Pallas kernels).
 """
 from __future__ import annotations
@@ -48,6 +48,26 @@ def _gaussian_window(size, sigma):
     return jnp.asarray(np.outer(g, g).astype(np.float32))
 
 
+def _histogram(bins, weights, n_bins: int):
+    """Weighted histograms of patches: bins, weights [K, p, p] ->
+    [K, n_bins], each pixel added in raster order.  A scatter-add leaves
+    the order of repeated bins to the backend, and a TPU picks it by the
+    batch shape (a tile's descriptors rounded differently in a batch of
+    16 and of 64); this loop fixes it on every backend."""
+    k = bins.shape[0]
+    bins = bins.reshape(k, -1)
+    weights = weights.reshape(k, -1)
+    ids = jnp.arange(n_bins, dtype=bins.dtype)
+
+    def add(p, hist):
+        b = jax.lax.dynamic_index_in_dim(bins, p, axis=1)          # [K, 1]
+        w = jax.lax.dynamic_index_in_dim(weights, p, axis=1)
+        return hist + jnp.where(b == ids, w, 0.0)
+
+    return jax.lax.fori_loop(0, bins.shape[1], add,
+                             jnp.zeros((k, n_bins), weights.dtype))
+
+
 def sift_descriptors(img, ys, xs, n_bins=8, n_cells=4, patch=16):
     """128-d SIFT descriptors at keypoints.  [K] -> [K, 128] (L2-normalized,
     0.2-clipped).  Orientation from a 36-bin gradient histogram; spatial
@@ -64,9 +84,7 @@ def sift_descriptors(img, ys, xs, n_bins=8, n_cells=4, patch=16):
     # --- dominant orientation: 36-bin weighted histogram -------------------
     w36 = _gaussian_window(patch, patch / 3.0)
     bins36 = jnp.floor((ang + np.pi) / (2 * np.pi) * 36).astype(jnp.int32) % 36
-    hist36 = jax.vmap(
-        lambda b, m: jnp.zeros((36,)).at[b.reshape(-1)].add(
-            (m * w36).reshape(-1)))(bins36, mag)
+    hist36 = _histogram(bins36, mag * w36, 36)
     theta = (jnp.argmax(hist36, axis=-1).astype(jnp.float32) + 0.5) \
         / 36.0 * 2 * np.pi - np.pi                          # [K]
 
@@ -78,9 +96,7 @@ def sift_descriptors(img, ys, xs, n_bins=8, n_cells=4, patch=16):
     cell_idx = (yy[:, None] * n_cells + yy[None, :]).astype(jnp.int32)
     flat_bin = cell_idx[None] * n_bins + obins               # [K,p,p]
     wgt = mag * _gaussian_window(patch, patch / 2.0)
-    desc = jax.vmap(
-        lambda b, m: jnp.zeros((n_cells * n_cells * n_bins,))
-        .at[b.reshape(-1)].add(m.reshape(-1)))(flat_bin, wgt)
+    desc = _histogram(flat_bin, wgt, n_cells * n_cells * n_bins)
     desc = desc / jnp.maximum(
         jnp.linalg.norm(desc, axis=-1, keepdims=True), 1e-6)
     desc = jnp.minimum(desc, 0.2)

@@ -69,30 +69,44 @@ FAST_OFFSETS = np.array([
 ], np.int32)   # (dy, dx)
 
 
-def _circle_values(img):
-    """Stack the 16 circle-neighbour images: [..., 16, H, W]."""
-    h, w = img.shape[-2], img.shape[-1]
-    p = jnp.pad(img, [(0, 0)] * (img.ndim - 2) + [(3, 3), (3, 3)],
-                mode="reflect")
-    vals = [p[..., 3 + dy:3 + dy + h, 3 + dx:3 + dx + w]
-            for dy, dx in FAST_OFFSETS]
-    return jnp.stack(vals, axis=-3)
+def _has_arc(flags, arc: int):
+    """OR over the 16 start positions of "flags[s .. s+arc-1] (mod 16) all
+    true".  ``runs[n][s]`` (all of flags[s .. s+n-1]) is built by
+    doubling, then the arc is the AND of its binary-decomposed pieces:
+    log2(arc) ANDs per start instead of arc - 1."""
+    runs = {1: flags}
+    n = 1
+    while 2 * n <= arc:
+        runs[2 * n] = [runs[n][s] & runs[n][(s + n) % 16] for s in range(16)]
+        n *= 2
+    arc_at, off = list(runs[n]), n
+    for p in sorted(runs, reverse=True):
+        if off + p <= arc:
+            arc_at = [a & runs[p][(s + off) % 16] for s, a in enumerate(arc_at)]
+            off += p
+    hit = arc_at[0]
+    for a in arc_at[1:]:
+        hit = hit | a
+    return hit
 
 
-def _arc_max_run(flags):
-    """flags [..., 16, H, W] bool -> max circular run length [..., H, W].
-
-    Branch-free: duplicate the ring, then a length-``n`` window is all-true
-    iff the windowed sum equals n; take the max window size via cumsum.
-    """
-    f = jnp.concatenate([flags, flags], axis=-3).astype(jnp.int32)
-    c = jnp.cumsum(f, axis=-3)                              # [..., 32, H, W]
-    c = jnp.concatenate([jnp.zeros_like(c[..., :1, :, :]), c], axis=-3)
-    best = jnp.zeros(flags.shape[:-3] + flags.shape[-2:], jnp.int32)
-    for n in range(1, 17):
-        run = (c[..., n:, :, :] - c[..., :-n, :, :]) == n   # any n-window
-        best = jnp.maximum(best, n * run.any(axis=-3).astype(jnp.int32))
-    return best
+def fast_from_padded(x, h: int, w: int, threshold: float, arc: int):
+    """FAST-N score of the [..., >= h+6, w+6] image ``x`` (3-px padded) ->
+    [..., h, w].  Branch-free: the 16 circle neighbours are shifted slices,
+    kept as a list — no [16, H, W] stack — so the working set stays a few
+    image-sized maps (a [16, 64, 560, 560] stack ran a 64-tile batch out
+    of a TPU v5e's 16 GB).  The Pallas kernel (`kernels/fastscore.py`)
+    runs this same function on VMEM values."""
+    center = x[..., 3:3 + h, 3:3 + w]
+    circ = [x[..., 3 + dy:3 + dy + h, 3 + dx:3 + dx + w]
+            for (dy, dx) in FAST_OFFSETS]
+    brighter = [c > center + threshold for c in circ]
+    darker = [c < center - threshold for c in circ]
+    is_corner = _has_arc(brighter, arc) | _has_arc(darker, arc)
+    diff = [jnp.abs(c - center) - threshold for c in circ]
+    score_b = sum(jnp.where(b, d, 0.0) for b, d in zip(brighter, diff))
+    score_d = sum(jnp.where(dk, d, 0.0) for dk, d in zip(darker, diff))
+    return jnp.where(is_corner, jnp.maximum(score_b, score_d), 0.0)
 
 
 def fast_score(img, threshold: float = 0.15, arc: int = 9,
@@ -102,17 +116,9 @@ def fast_score(img, threshold: float = 0.15, arc: int = 9,
     if use_pallas:
         from repro.kernels.ops import fast_score as _pallas
         return _pallas(img, threshold=threshold, arc=arc)
-    circ = _circle_values(img)                              # [..., 16, H, W]
-    center = img[..., None, :, :]
-    brighter = circ > center + threshold
-    darker = circ < center - threshold
-    run_b = _arc_max_run(brighter)
-    run_d = _arc_max_run(darker)
-    is_corner = (run_b >= arc) | (run_d >= arc)
-    diff = jnp.abs(circ - center) - threshold
-    score_b = jnp.where(brighter, diff, 0.0).sum(axis=-3)
-    score_d = jnp.where(darker, diff, 0.0).sum(axis=-3)
-    return jnp.where(is_corner, jnp.maximum(score_b, score_d), 0.0)
+    p = jnp.pad(img, [(0, 0)] * (img.ndim - 2) + [(3, 3), (3, 3)],
+                mode="reflect")
+    return fast_from_padded(p, img.shape[-2], img.shape[-1], threshold, arc)
 
 
 # ---------------------------------------------------------------------------
@@ -190,9 +196,9 @@ def surf_hessian_response(img, use_pallas: bool = False):
 
     ``use_pallas`` is accepted for a uniform detector signature but the
     integral-image path is *pallas-exempt* (DESIGN.md §6): the summed-area
-    table is two cumsums + 8 gathers — already a single memory-bound sweep
-    with no per-level rebuild to fuse, and ``jnp.cumsum`` lowers to an
-    efficient scan that a hand-written kernel would not beat.
+    table is two prefix scans + 8 gathers — already a single memory-bound
+    sweep with no per-level rebuild to fuse, and the scans lower to plain
+    adds that a hand-written kernel would not beat.
     """
     del use_pallas  # integral-image path is pallas-exempt (see docstring)
     ii = integral_image(img)

@@ -175,14 +175,22 @@ def _reduce_features(per_tile):
     flat_valid = per_tile["valid"].reshape(t * k)
     masked = jnp.where(flat_valid, flat_scores, -jnp.inf)
     top_scores, idx = jax.lax.top_k(masked, min(k * 4, t * k))
-    gather = lambda a: jnp.take(a.reshape(t * k, *a.shape[2:]), idx, axis=0)
+    top_valid = jnp.isfinite(top_scores)
+
+    def gather(a):
+        # invalid picks tie at -inf in whatever order the backend's top_k
+        # leaves them: zero their payload so it never depends on that order
+        g = jnp.take(a.reshape(t * k, *a.shape[2:]), idx, axis=0)
+        return jnp.where(top_valid.reshape(-1, *[1] * (g.ndim - 1)), g,
+                         jnp.zeros_like(g))
+
     result = {
         "total_count": total,
         "per_tile_count": per_tile["count"],
-        "top_scores": jnp.where(jnp.isfinite(top_scores), top_scores, 0.0),
+        "top_scores": jnp.where(top_valid, top_scores, 0.0),
         "top_ys": gather(per_tile["ys"]),
         "top_xs": gather(per_tile["xs"]),
-        "top_valid": gather(per_tile["valid"]) & jnp.isfinite(top_scores),
+        "top_valid": gather(per_tile["valid"]),
         "keypoint_count": per_tile["valid"].sum(),
     }
     if "desc" in per_tile:
@@ -200,6 +208,22 @@ def extract_features(bundle_tiles, bundle_headers, algorithm: str,
     return _reduce_features(per_tile)
 
 
+def map_tiles_multi(bundle_tiles, bundle_headers, algorithms,
+                    cfg: DifetConfig, use_pallas: bool = False):
+    """The map alone: per-tile features of every algorithm,
+    {algorithm: {key: [N, ...]}} — no cross-tile work, so a mesh can run
+    it per device shard (`core/job.py::DifetJob` wraps it in
+    ``shard_map``)."""
+    return jax.vmap(functools.partial(extract_tile_multi, tuple(algorithms),
+                                      cfg, use_pallas=use_pallas))(
+        bundle_tiles, bundle_headers)
+
+
+def reduce_features_multi(per_tile):
+    """The reduce of `map_tiles_multi`'s output, per algorithm."""
+    return {alg: _reduce_features(r) for alg, r in per_tile.items()}
+
+
 def extract_features_multi(bundle_tiles, bundle_headers, algorithms,
                            cfg: DifetConfig, use_pallas: bool = False):
     """Multi-algorithm extraction with shared response maps: one vmapped map
@@ -207,12 +231,8 @@ def extract_features_multi(bundle_tiles, bundle_headers, algorithms,
     single FAST score), then each algorithm gets its own reduce.  Returns
     {algorithm: result} with per-algorithm results identical to
     ``extract_features`` (same ops on the same inputs)."""
-    algorithms = tuple(algorithms)
-    per_tile = jax.vmap(
-        functools.partial(extract_tile_multi, algorithms, cfg,
-                          use_pallas=use_pallas))(
-        bundle_tiles, bundle_headers)
-    return {alg: _reduce_features(per_tile[alg]) for alg in algorithms}
+    return reduce_features_multi(map_tiles_multi(
+        bundle_tiles, bundle_headers, algorithms, cfg, use_pallas))
 
 
 def extract_request_features(bundle_tiles, bundle_headers, algorithms,
@@ -248,23 +268,3 @@ def make_serve_step(algorithms, cfg: DifetConfig, use_pallas: bool = False):
     return jax.jit(functools.partial(
         extract_request_features, algorithms=tuple(algorithms), cfg=cfg,
         use_pallas=use_pallas))
-
-
-def make_distributed_extractor(algorithm: str, cfg: DifetConfig, mesh,
-                               use_pallas: bool = False):
-    """jit-compiled distributed extractor: tiles sharded over the data axis;
-    the reduce lowers to one all-reduce (counts) + one gather (top-K)."""
-    from repro.distributed.sharding import use_mesh, batch_pspec
-    from jax.sharding import NamedSharding
-
-    tile_sh = NamedSharding(mesh, batch_pspec(mesh, 3))
-    hdr_sh = NamedSharding(mesh, batch_pspec(mesh, 2))
-
-    fn = functools.partial(extract_features, algorithm=algorithm, cfg=cfg,
-                           use_pallas=use_pallas)
-
-    @functools.partial(jax.jit, in_shardings=(tile_sh, hdr_sh))
-    def run(tiles, headers):
-        return fn(tiles, headers)
-
-    return run

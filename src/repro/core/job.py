@@ -37,7 +37,8 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core.bundle import BundleStore, TileBundle
-from repro.core.engine import extract_features, extract_features_multi
+from repro.core.engine import (extract_features, extract_features_multi,
+                               map_tiles_multi, reduce_features_multi)
 from repro.obs import metrics as obs_metrics
 
 
@@ -353,20 +354,28 @@ class DifetJob(ManifestJob):
     def _sharded_fn(self, tiles_shape, cfg) -> Callable:
         """One jitted, input-sharded program per (algorithms, batch shape,
         config); cached so a streaming pipeline's fixed-shape batches
-        compile exactly once."""
+        compile exactly once.  The per-tile map runs under ``shard_map``
+        (each device maps its own tiles — the compiler cannot partition
+        a Pallas kernel by itself), and the reduce runs on the gathered
+        per-tile results."""
         import functools
         import jax
         from jax.sharding import NamedSharding
         from repro.distributed.sharding import batch_pspec
         key = (self.algorithms, tuple(tiles_shape), cfg)
         if key not in self._sharded_fns:
-            shardings = (NamedSharding(self.mesh, batch_pspec(self.mesh, 3)),
-                         NamedSharding(self.mesh, batch_pspec(self.mesh, 2)))
-            self._sharded_fns[key] = jax.jit(
-                functools.partial(extract_features_multi,
+            specs = (batch_pspec(self.mesh, 3), batch_pspec(self.mesh, 2))
+            per_tile = jax.shard_map(
+                functools.partial(map_tiles_multi,
                                   algorithms=self.algorithms, cfg=cfg,
                                   use_pallas=self.use_pallas),
-                in_shardings=shardings)
+                mesh=self.mesh, in_specs=specs,
+                out_specs=batch_pspec(self.mesh, 1),
+                check_vma=False)       # pallas_call outputs carry no vma
+            self._sharded_fns[key] = jax.jit(
+                lambda t, h: reduce_features_multi(per_tile(t, h)),
+                in_shardings=tuple(NamedSharding(self.mesh, s)
+                                   for s in specs))
         return self._sharded_fns[key]
 
     @staticmethod

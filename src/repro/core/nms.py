@@ -57,7 +57,7 @@ def topk_keypoints(resp, k: int, threshold, mask):
     """Select up to K strongest responses.
 
     Returns (ys [K], xs [K], scores [K], valid [K]) — fixed shapes; invalid
-    slots have score 0 and valid=False.  Ties broken by flat index so the
+    slots have score 0, coordinates 0 and valid=False.  Ties broken by flat index so the
     selection is deterministic and partition-invariant.
     """
     h, w = resp.shape[-2:]
@@ -65,9 +65,12 @@ def topk_keypoints(resp, k: int, threshold, mask):
         *resp.shape[:-2], h * w)
     scores, idx = lax.top_k(flat, k)
     valid = jnp.isfinite(scores)
+    # invalid slots tie at -inf, and a backend may order ties as it likes
+    # (TPU top_k does not keep index order among them): zero their payload
+    # so results never depend on that order
     scores = jnp.where(valid, scores, 0.0)
-    ys = (idx // w).astype(jnp.int32)
-    xs = (idx % w).astype(jnp.int32)
+    ys = jnp.where(valid, idx // w, 0).astype(jnp.int32)
+    xs = jnp.where(valid, idx % w, 0).astype(jnp.int32)
     return ys, xs, scores, valid
 
 

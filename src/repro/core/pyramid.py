@@ -238,8 +238,15 @@ def sobel_gradients(img):
 
 
 def integral_image(img):
-    """Summed-area table with a leading zero row/col: [..., H+1, W+1]."""
-    ii = jnp.cumsum(jnp.cumsum(img, axis=-2), axis=-1)
+    """Summed-area table with a leading zero row/col: [..., H+1, W+1].
+
+    The prefix sums are an explicit ``associative_scan``, not
+    ``jnp.cumsum``: a TPU lowers cumsum to a windowed reduction whose
+    accumulation blocks it picks by the batch shape, so the same tile
+    summed in a batch of 16 and of 64 rounded differently.  Explicit adds
+    fix the order on every backend and batch."""
+    scan = functools.partial(jax.lax.associative_scan, jnp.add)
+    ii = scan(scan(img, axis=img.ndim - 2), axis=img.ndim - 1)
     return jnp.pad(ii, [(0, 0)] * (img.ndim - 2) + [(1, 0), (1, 0)])
 
 

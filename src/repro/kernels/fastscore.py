@@ -1,57 +1,26 @@
 """FAST-N segment-test score Pallas kernel.
 
 Hardware adaptation (DESIGN.md §5): the CPU/OpenCV FAST is branchy (early
-exit on the 1-5-9-13 probe); on the TPU VPU we re-formulate branch-free —
-all 16 circle neighbours are shifted VMEM slices, the "contiguous arc of
-length >= N" test becomes an OR over 16 of an AND over N static shifted
-boolean stacks, and the score is a masked reduction.  Everything stays in
-VMEM; one HBM read per tile.
+exit on the 1-5-9-13 probe); on the TPU VPU it is re-formulated
+branch-free (`core/detectors.py::fast_from_padded`, shared with the jnp
+path): the 16 circle neighbours are shifted VMEM slices, the "contiguous
+arc of length >= N" test is an OR over 16 start positions of an AND over
+the arc (built by run doubling: log2 N ANDs per start, not N - 1), and
+the score is a masked sum.  One HBM read per tile, computed in row strips
+(`kernels/strips.py`): unrolled over a whole 560² tile the segment test
+made a program the TPU compiler did not finish in minutes.
 """
 from __future__ import annotations
 
 import functools
 
-from jax.experimental import pallas as pl
-import jax.numpy as jnp
-
-from repro.core.detectors import FAST_OFFSETS
-
-
-def fast_kernel(x_ref, o_ref, *, threshold: float, arc: int, h: int, w: int):
-    """x_ref: [1, h+6, w+6]; o_ref: [1, h, w]."""
-    x = x_ref[0]
-    center = x[3:3 + h, 3:3 + w]
-    circ = [x[3 + dy:3 + dy + h, 3 + dx:3 + dx + w]
-            for (dy, dx) in FAST_OFFSETS]
-    brighter = [c > center + threshold for c in circ]
-    darker = [c < center - threshold for c in circ]
-
-    def has_arc(flags):
-        hit = jnp.zeros((h, w), jnp.bool_)
-        for start in range(16):
-            run = flags[start % 16]
-            for j in range(1, arc):
-                run = run & flags[(start + j) % 16]
-            hit = hit | run
-        return hit
-
-    is_corner = has_arc(brighter) | has_arc(darker)
-    diff = [jnp.abs(c - center) - threshold for c in circ]
-    score_b = sum(jnp.where(b, d, 0.0) for b, d in zip(brighter, diff))
-    score_d = sum(jnp.where(dk, d, 0.0) for dk, d in zip(darker, diff))
-    o_ref[0] = jnp.where(is_corner, jnp.maximum(score_b, score_d), 0.0)
+from repro.core.detectors import fast_from_padded
+from repro.kernels.strips import strip_pallas
 
 
 def fast_pallas(x_padded, *, threshold: float, arc: int, h: int, w: int,
                 interpret: bool):
-    n, hp, wp = x_padded.shape
-    kern = functools.partial(fast_kernel, threshold=threshold, arc=arc,
-                             h=h, w=w)
-    return pl.pallas_call(
-        kern,
-        grid=(n,),
-        in_specs=[pl.BlockSpec((1, hp, wp), lambda i: (i, 0, 0))],
-        out_specs=pl.BlockSpec((1, h, w), lambda i: (i, 0, 0)),
-        out_shape=jnp.zeros((n, h, w), jnp.float32),
-        interpret=interpret,
-    )(x_padded)
+    body = functools.partial(fast_from_padded, w=w, threshold=threshold,
+                             arc=arc)
+    return strip_pallas(body, x_padded, h=h, w=w, halo=3,
+                        interpret=interpret, name="fast_score")

@@ -5,10 +5,10 @@ maps, 3 blurred maps, response); this kernel does ONE: the padded tile is
 DMA'd to VMEM, and gradients → products → separable Gaussian window →
 response are all computed on VMEM values.
 
-Grid: one program per tile (tiles are the DIFET work unit, 560² fp32 ≈
-1.25 MiB — the full working set of ~8 live buffers ≈ 10 MiB fits v5e VMEM).
-The lane dim (W) is padded to a 128 multiple by the caller (ops.py) so the
-VPU sees aligned vectors.
+Grid: one padded tile per DMA, computed in row strips
+(`kernels/strips.py`) so each program's working set is a strip's, not a
+whole 560² tile's.  The lane dim (W) is padded to a 128 multiple by the
+caller (ops.py) so the VPU sees aligned vectors.
 
 Gaussian taps are compile-time constants (sigma is static per pallas_call),
 so the separable window unrolls into 2·(2r+1) fused multiply-adds.
@@ -17,11 +17,10 @@ from __future__ import annotations
 
 import functools
 
-import numpy as np
-from jax.experimental import pallas as pl
 import jax.numpy as jnp
 
 from repro.core.pyramid import gaussian_kernel_1d
+from repro.kernels.strips import strip_pallas
 
 
 def _sobel_vmem(x, h, w):
@@ -41,39 +40,29 @@ def _blur_vmem(x, taps, h, w):
     return sum(float(taps[i]) * tmp[i:i + h, :] for i in range(2 * r + 1))
 
 
-def harris_kernel(x_ref, o_ref, *, k: float, taps, shi_tomasi: bool,
-                  h: int, w: int):
-    """x_ref: [1, h + 2*(r+1), w + 2*(r+1)]; o_ref: [1, h, w]."""
+def harris_strip(x, rows: int, *, k: float, taps, shi_tomasi: bool, w: int):
+    """x: [>= rows + 2*(r+1), w + 2*(r+1)] -> response [rows, w]."""
     r = (len(taps) - 1) // 2
-    x = x_ref[0]
     # gradients on the blur-padded extent (valid for blurring afterwards)
-    gx, gy = _sobel_vmem(x, h + 2 * r, w + 2 * r)
-    ixx = _blur_vmem(gx * gx, taps, h, w)
-    iyy = _blur_vmem(gy * gy, taps, h, w)
-    ixy = _blur_vmem(gx * gy, taps, h, w)
+    gx, gy = _sobel_vmem(x, rows + 2 * r, w + 2 * r)
+    ixx = _blur_vmem(gx * gx, taps, rows, w)
+    iyy = _blur_vmem(gy * gy, taps, rows, w)
+    ixy = _blur_vmem(gx * gy, taps, rows, w)
     if shi_tomasi:
         half_tr = 0.5 * (ixx + iyy)
         rad = jnp.sqrt(jnp.maximum(0.25 * (ixx - iyy) ** 2 + ixy * ixy, 0.0))
-        resp = half_tr - rad
-    else:
-        det = ixx * iyy - ixy * ixy
-        tr = ixx + iyy
-        resp = det - k * tr * tr
-    o_ref[0] = resp
+        return half_tr - rad
+    det = ixx * iyy - ixy * ixy
+    tr = ixx + iyy
+    return det - k * tr * tr
 
 
 def harris_pallas(x_padded, *, k: float, sigma: float, shi_tomasi: bool,
                   h: int, w: int, interpret: bool):
     """x_padded: [n, h+2p, w+2p] with p = blur_radius + 1."""
     taps = tuple(gaussian_kernel_1d(float(sigma)).tolist())
-    n, hp, wp = x_padded.shape
-    kern = functools.partial(harris_kernel, k=k, taps=taps,
-                             shi_tomasi=shi_tomasi, h=h, w=w)
-    return pl.pallas_call(
-        kern,
-        grid=(n,),
-        in_specs=[pl.BlockSpec((1, hp, wp), lambda i: (i, 0, 0))],
-        out_specs=pl.BlockSpec((1, h, w), lambda i: (i, 0, 0)),
-        out_shape=jnp.zeros((n, h, w), jnp.float32),
-        interpret=interpret,
-    )(x_padded)
+    body = functools.partial(harris_strip, k=k, taps=taps,
+                             shi_tomasi=shi_tomasi, w=w)
+    return strip_pallas(body, x_padded, h=h, w=w, halo=(len(taps) + 1) // 2,
+                        interpret=interpret,
+                        name="shi_tomasi" if shi_tomasi else "harris")

@@ -10,7 +10,7 @@ Two Pallas kernels cover the database-size spectrum:
 
 * **Resident** (`match_pallas`): the whole database stays VMEM-resident
   across the query grid; each program owns one ``QBLOCK``-query block and
-  scans the database in chunks that never leave VMEM.  Cheapest when the
+  scans the database in chunks that never leave VMEM (a rolled loop).  Cheapest when the
   database fits the VMEM budget (``ops.matcher_fits_vmem``).
 * **Streaming** (`match_pallas_stream`): a second *database* grid
   dimension tiles the database into ``KBLOCK``-row chunks that Pallas
@@ -26,7 +26,8 @@ Distance formulations (identical across kernels and jnp paths):
   reduction — 5 integer VPU ops per word) summed over words.  Distances
   are exact int32, so kernel/oracle/fallback agree *bit-identically*.
 * **L2 (SIFT/SURF)**: the ``|q|^2 + |k|^2 - 2 q.k`` expansion; the q.k
-  block is one MXU ``dot_general`` per chunk, fp32-accumulated.  The
+  block is one MXU ``dot_general`` per chunk at HIGHEST precision
+  (fp32 inputs, fp32 accumulation — see `_chunk_dist`).  The
   ``|q|^2`` term is constant per query row, so the scan ranks on the
   partial ``|k|^2 - 2 q.k`` and adds ``|q|^2`` once at the end — no
   per-chunk re-broadcast of the query norms over the [Q, C] block.
@@ -40,8 +41,8 @@ site to whichever wins on the current host.
 
 Invalid database slots (validity masks come from capacity-K extraction)
 are forced to a BIG distance before the running update; ties are broken
-toward the smallest database index (``argmin`` first-occurrence + a
-strictly-less merge), so matches are deterministic and partition-invariant
+toward the smallest database index (first occurrence of the chunk
+minimum + a strictly-less merge), so matches are deterministic and partition-invariant
 — in every path, streaming included (chunks merge in database order).
 """
 from __future__ import annotations
@@ -57,10 +58,10 @@ BIG_HAMMING = 1 << 30     # > any packed-bit distance; < int32 max
 
 
 def kchunk_for(metric: str) -> int:
-    """Database rows per VMEM-resident chunk.  Hamming holds a [Q, C, W]
-    XOR/popcount intermediate (W words per descriptor), so it chunks 4x
-    finer than L2, whose per-chunk state is just the [Q, C] distance
-    block coming off the MXU."""
+    """Database rows per VMEM-resident chunk: the width of the [Q, C]
+    distance block one loop step scores.  Hamming builds it from W
+    per-word XOR/popcount passes (~12 VPU ops per element per word), so
+    it chunks 4x finer than L2, whose block comes off the MXU."""
     return 256 if metric == "hamming" else 1024
 
 
@@ -87,28 +88,43 @@ def popcount32(x):
     return (x * 0x01010101) >> 24          # byte-sum via overflowing multiply
 
 
-def _chunk_dist(q, c, m, metric, big, dn=None):
+def _chunk_dist(q, ct, m, metric, big, dn=None):
     """Distances of one DB chunk: [Q, C], invalid slots forced to big.
-    L2 omits the |q|^2 term (constant per row — callers add it once at
-    the end of the scan); ``dn`` lets callers pass a precomputed |k|^2."""
+
+    ``ct`` is the chunk *transposed*, [D, C], and ``m`` its [1, C]
+    validity row, so database rows run along the lanes: the L2 ``q.k``
+    block is a plain [Q, D] x [D, C] MXU matmul, ``|k|^2`` reduces over
+    sublanes into a lane row, and Hamming XORs one [Q, 1] query word
+    column against one [1, C] database word row per word — no [Q, C, W]
+    intermediate with a W-word lane axis.  L2 omits the |q|^2 term
+    (constant per row — callers add it once at the end of the scan);
+    ``dn`` lets callers pass a precomputed [1, C] |k|^2.  The matmul runs
+    at HIGHEST precision: a TPU's default f32 matmul rounds its inputs to
+    bfloat16, which would make the TPU's distances (and so its matches)
+    depend on the path."""
     if metric == "hamming":
-        x = q[:, None, :] ^ c[None, :, :]               # [Q, C, W]
-        d = popcount32(x).astype(jnp.int32).sum(axis=-1)
+        d = sum(popcount32(q[:, w:w + 1] ^ ct[w:w + 1, :]).astype(jnp.int32)
+                for w in range(q.shape[1]))
     else:
-        dot = jax.lax.dot_general(q, c, (((1,), (1,)), ((), ())),
+        dot = jax.lax.dot_general(q, ct, (((1,), (0,)), ((), ())),
+                                  precision=jax.lax.Precision.HIGHEST,
                                   preferred_element_type=jnp.float32)
-        dn = jnp.sum(c * c, axis=-1) if dn is None else dn
-        d = dn[None, :] - 2.0 * dot
-    return jnp.where(m[None, :] != 0, d, big)
+        dn = jnp.sum(ct * ct, axis=0, keepdims=True) if dn is None else dn
+        d = dn - 2.0 * dot
+    return jnp.where(m != 0, d, big)
 
 
 def _chunk_best2(d, start, big):
-    """Best/second/argbest of one [Q, C] distance chunk; indices global."""
-    arg = jnp.argmin(d, axis=1).astype(jnp.int32)   # first occurrence = smallest idx
-    best = jnp.min(d, axis=1)
+    """Best/second/argbest of one [Q, C] distance chunk as [Q, 1] columns;
+    indices global.  The argbest is the smallest column holding the
+    minimum (argmin's first occurrence) as a min over an iota: Mosaic
+    lowers argmin only for float32, and Hamming distances are int32."""
+    best = jnp.min(d, axis=1, keepdims=True)
     cols = jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
-    second = jnp.min(jnp.where(cols == arg[:, None], big, d), axis=1)
-    return best, second, arg + jnp.int32(start)
+    arg = jnp.min(jnp.where(d == best, cols, d.shape[1]), axis=1,
+                  keepdims=True)
+    second = jnp.min(jnp.where(cols == arg, big, d), axis=1, keepdims=True)
+    return best, second, arg + start
 
 
 def _merge_best2(carry, chunk):
@@ -125,64 +141,43 @@ def _merge_best2(carry, chunk):
 
 
 def _l2_qnorm(q, best, second):
-    """Fold the per-query |q|^2 back into the scanned partial distances
-    (masked slots are +inf, which absorbs the add)."""
-    qn = jnp.sum(q * q, axis=-1)
+    """Fold the per-query |q|^2 back into the scanned partial [Q, 1]
+    distances (masked slots are +inf, which absorbs the add)."""
+    qn = jnp.sum(q * q, axis=-1, keepdims=True)
     return best + qn, second + qn
 
 
-def best2_scan(q, db, db_valid, *, metric: str, kchunk: int = None):
-    """Running best/second-best over database chunks (unrolled loop).
+def _init_best2(nq: int, big):
+    return (jnp.full((nq, 1), big), jnp.full((nq, 1), big),
+            jnp.zeros((nq, 1), jnp.int32))
 
-    q [Q, D], db [K, D], db_valid [K] (bool or int) -> (best [Q],
-    second [Q], idx [Q] int32).  This is the exact per-block formulation
-    the resident kernel runs on VMEM values; on plain arrays it doubles
-    as a small-database jnp path.  The python loop unrolls into the
-    trace, so it is only for databases a few chunks long — `best2_stream`
-    is the rolled (lax.scan) twin for large databases.
-    """
-    nq, nk = q.shape[0], db.shape[0]
-    kchunk = kchunk_for(metric) if kchunk is None else kchunk
-    big = big_for(metric)
-    if metric == "l2":
-        dn = jnp.sum(db * db, axis=-1)
-    elif metric != "hamming":
-        raise ValueError(f"unknown metric {metric!r}")
-    best = jnp.full((nq,), big)
-    second = jnp.full((nq,), big)
-    bidx = jnp.zeros((nq,), jnp.int32)
-    for start in range(0, nk, kchunk):
-        c = db[start:start + kchunk]
-        m = db_valid[start:start + kchunk]
-        d = _chunk_dist(q, c, m, metric, big,
-                        dn=None if metric == "hamming"
-                        else dn[start:start + kchunk])
-        best, second, bidx = _merge_best2(
-            (best, second, bidx), _chunk_best2(d, start, big))
-    if metric == "l2":
-        best, second = _l2_qnorm(q, best, second)
-    return best, second, bidx
+
+def _columns_to_vectors(best, second, bidx):
+    return best[:, 0], second[:, 0], bidx[:, 0]
 
 
 def best2_full(q, db, db_valid, *, metric: str):
     """One-block best/second-best: the whole [Q, K] distance matrix in a
     single chunk.  On hosts where materializing the matrix is cheap (CPU
     XLA; small K) this is the fastest formulation — the dispatcher picks
-    it per backend (`kernels/dispatch.py`)."""
+    it per backend (`kernels/dispatch.py`).  q [Q, D], db [K, D],
+    db_valid [K] -> (best [Q], second [Q], idx [Q] int32)."""
     big = big_for(metric)
-    d = _chunk_dist(q, db, db_valid, metric, big)
+    d = _chunk_dist(q, db.T, db_valid.astype(jnp.int32)[None, :], metric,
+                    big)
     best, second, bidx = _chunk_best2(d, 0, big)
     if metric == "l2":
         best, second = _l2_qnorm(q, best, second)
-    return best, second, bidx
+    return _columns_to_vectors(best, second, bidx)
 
 
 def best2_stream(q, db, db_valid, *, metric: str, kchunk: int = None):
-    """Rolled streaming scan: ``lax.scan`` over [K/C, C]-chunked database
-    slabs with carried (best, second, argbest) registers — the jnp twin
-    of the streaming Pallas kernel, and the path that lets one query
-    batch scan millions of descriptors on any backend (constant working
-    set, no [Q, K] materialization, trace size independent of K).
+    """Rolled streaming scan: ``lax.scan`` over [K/C, D, C]-chunked
+    (transposed) database slabs with carried (best, second, argbest)
+    registers — the jnp twin of the streaming Pallas kernel, and the path
+    that lets one query batch scan millions of descriptors on any backend
+    (constant working set, no [Q, K] materialization, trace size
+    independent of K).
 
     The database is zero-padded to a chunk multiple (padding rows are
     masked invalid), so tail chunks need no special casing.
@@ -192,78 +187,104 @@ def best2_stream(q, db, db_valid, *, metric: str, kchunk: int = None):
     big = big_for(metric)
     if metric not in ("hamming", "l2"):
         raise ValueError(f"unknown metric {metric!r}")
+    db_valid = db_valid.astype(jnp.int32)
     pad = (-nk) % kchunk
     if pad:
         db = jnp.pad(db, ((0, pad), (0, 0)))
-        db_valid = jnp.pad(db_valid.astype(jnp.int32), (0, pad))
+        db_valid = jnp.pad(db_valid, (0, pad))
     n_chunks = (nk + pad) // kchunk
-    dbc = db.reshape(n_chunks, kchunk, db.shape[1])
-    mc = db_valid.reshape(n_chunks, kchunk)
+    dbc = db.reshape(n_chunks, kchunk, db.shape[1]).transpose(0, 2, 1)
+    mc = db_valid.reshape(n_chunks, 1, kchunk)
 
     def step(carry, xs):
-        c, m, start = xs
-        d = _chunk_dist(q, c, m, metric, big)
+        ct, m, start = xs
+        d = _chunk_dist(q, ct, m, metric, big)
         return _merge_best2(carry, _chunk_best2(d, start, big)), None
 
-    init = (jnp.full((nq,), big), jnp.full((nq,), big),
-            jnp.zeros((nq,), jnp.int32))
     starts = jnp.arange(n_chunks, dtype=jnp.int32) * kchunk
-    (best, second, bidx), _ = jax.lax.scan(step, init, (dbc, mc, starts))
+    (best, second, bidx), _ = jax.lax.scan(step, _init_best2(nq, big),
+                                           (dbc, mc, starts))
     if metric == "l2":
         best, second = _l2_qnorm(q, best, second)
-    return best, second, bidx
+    return _columns_to_vectors(best, second, bidx)
+
+
+# ---- Pallas kernels ---------------------------------------------------------
+#
+# Both kernels take the database transposed, [D, K], with its validity as
+# a [1, K] int32 row, and write (best, second, idx) as [NQ, 1] columns in
+# [QBLOCK, 1] blocks: the lane reductions over a [QBLOCK, C] distance
+# block leave one value per sublane row, which is exactly that layout.
+
+def _best2_out_shapes(nq: int, dist_dt):
+    return [jax.ShapeDtypeStruct((nq, 1), dist_dt),
+            jax.ShapeDtypeStruct((nq, 1), dist_dt),
+            jax.ShapeDtypeStruct((nq, 1), jnp.int32)]
 
 
 # ---- resident kernel (whole DB in VMEM across the query grid) --------------
 
-def match_kernel(q_ref, db_ref, mask_ref, best_ref, sec_ref, idx_ref, *,
+def match_kernel(q_ref, dbt_ref, mask_ref, best_ref, sec_ref, idx_ref, *,
                  metric: str, kchunk: int):
-    """q_ref [QBLOCK, D]; db_ref [K, D] (whole DB, VMEM-resident across the
-    query grid); mask_ref [1, K] int32; outputs [1, QBLOCK] each."""
-    b, s, i = best2_scan(q_ref[...], db_ref[...], mask_ref[0],
-                         metric=metric, kchunk=kchunk)
-    best_ref[0] = b
-    sec_ref[0] = s
-    idx_ref[0] = i
+    """q_ref [QBLOCK, D]; dbt_ref [D, K] (whole DB, VMEM-resident across
+    the query grid; K a ``kchunk`` multiple); mask_ref [1, K] int32;
+    outputs [QBLOCK, 1] each.  A rolled loop scans the resident DB in
+    ``kchunk``-column slices, so the program's size does not grow with
+    K."""
+    q = q_ref[...]
+    big = big_for(metric)
+
+    def chunk(c, carry):
+        start = pl.multiple_of(c * kchunk, kchunk)
+        d = _chunk_dist(q, dbt_ref[:, pl.ds(start, kchunk)],
+                        mask_ref[:, pl.ds(start, kchunk)], metric, big)
+        return _merge_best2(carry, _chunk_best2(d, start, big))
+
+    b, s, i = jax.lax.fori_loop(0, dbt_ref.shape[1] // kchunk, chunk,
+                                _init_best2(q.shape[0], big))
+    if metric == "l2":
+        b, s = _l2_qnorm(q, b, s)
+    best_ref[...] = b
+    sec_ref[...] = s
+    idx_ref[...] = i
 
 
-def match_pallas(q, db, db_mask, *, metric: str, interpret: bool,
+def match_pallas(q, dbt, db_mask, *, metric: str, interpret: bool,
                  kchunk: int = None):
-    """q [NQ, D] (NQ a QBLOCK multiple), db [NK, D], db_mask [1, NK] int32
-    -> (best [NQ], second [NQ], idx [NQ])."""
+    """q [NQ, D] (NQ a QBLOCK multiple), dbt [D, NK] (NK a ``kchunk``
+    multiple — pad rows masked invalid), db_mask [1, NK] int32 ->
+    (best [NQ], second [NQ], idx [NQ])."""
     nq, d = q.shape
-    nk = db.shape[0]
+    nk = dbt.shape[1]
     kchunk = kchunk_for(metric) if kchunk is None else kchunk
     dist_dt = jnp.int32 if metric == "hamming" else jnp.float32
-    grid = (nq // QBLOCK,)
     kern = functools.partial(match_kernel, metric=metric, kchunk=kchunk)
     outs = pl.pallas_call(
         kern,
-        grid=grid,
+        grid=(nq // QBLOCK,),
         in_specs=[pl.BlockSpec((QBLOCK, d), lambda i: (i, 0)),
-                  pl.BlockSpec((nk, d), lambda i: (0, 0)),
+                  pl.BlockSpec((d, nk), lambda i: (0, 0)),
                   pl.BlockSpec((1, nk), lambda i: (0, 0))],
-        out_specs=[pl.BlockSpec((1, QBLOCK), lambda i: (i, 0))] * 3,
-        out_shape=[jax.ShapeDtypeStruct((grid[0], QBLOCK), dist_dt),
-                   jax.ShapeDtypeStruct((grid[0], QBLOCK), dist_dt),
-                   jax.ShapeDtypeStruct((grid[0], QBLOCK), jnp.int32)],
+        out_specs=[pl.BlockSpec((QBLOCK, 1), lambda i: (i, 0))] * 3,
+        out_shape=_best2_out_shapes(nq, dist_dt),
         interpret=interpret,
-    )(q, db, db_mask)
+        name=f"match_resident_{metric}",
+    )(q, dbt, db_mask)
     return tuple(o.reshape(-1) for o in outs)
 
 
 # ---- streaming kernel (tiled DB grid, carried registers) -------------------
 
-def stream_kernel(q_ref, db_ref, mask_ref, best_ref, sec_ref, idx_ref, *,
+def stream_kernel(q_ref, dbt_ref, mask_ref, best_ref, sec_ref, idx_ref, *,
                   metric: str, kblock: int, n_kblocks: int):
     """One (query-block, DB-chunk) grid step of the streaming matcher.
 
     The DB axis is the *minor* grid dimension, so for a fixed query block
-    the output refs map to the same [1, QBLOCK] block across every DB
+    the output refs map to the same [QBLOCK, 1] block across every DB
     step — Pallas keeps them VMEM-resident between revisits, making them
     the carried (best, second, argbest) registers; they are initialized
     at the first chunk and written back to HBM only after the last.
-    Meanwhile ``db_ref``/``mask_ref`` advance along the DB grid, which
+    Meanwhile ``dbt_ref``/``mask_ref`` advance along the DB grid, which
     Pallas pipelines as double-buffered HBM→VMEM DMA (chunk k+1 streams
     in while chunk k is scored).  L2 scans the qn-free partial distance
     and folds |q|^2 in at the final chunk (see module docstring)."""
@@ -278,26 +299,25 @@ def stream_kernel(q_ref, db_ref, mask_ref, best_ref, sec_ref, idx_ref, *,
         idx_ref[...] = jnp.zeros(idx_ref.shape, jnp.int32)
 
     q = q_ref[...]
-    d = _chunk_dist(q, db_ref[...], mask_ref[0], metric, big)
-    chunk = _chunk_best2(d, 0, big)
-    chunk = (chunk[0], chunk[1], chunk[2] + ki * kblock)  # global indices
+    d = _chunk_dist(q, dbt_ref[...], mask_ref[...], metric, big)
     best, second, bidx = _merge_best2(
-        (best_ref[0], sec_ref[0], idx_ref[0]), chunk)
-    idx_ref[0] = bidx
+        (best_ref[...], sec_ref[...], idx_ref[...]),
+        _chunk_best2(d, ki * kblock, big))          # global indices
+    idx_ref[...] = bidx
     if metric == "l2":
         last = ki == n_kblocks - 1
-        qn = jnp.sum(q * q, axis=-1)
-        best_ref[0] = jnp.where(last, best + qn, best)
-        sec_ref[0] = jnp.where(last, second + qn, second)
+        best_q, second_q = _l2_qnorm(q, best, second)
+        best_ref[...] = jnp.where(last, best_q, best)
+        sec_ref[...] = jnp.where(last, second_q, second)
     else:
-        best_ref[0] = best
-        sec_ref[0] = second
+        best_ref[...] = best
+        sec_ref[...] = second
 
 
-def match_pallas_stream(q, db, db_mask, *, metric: str, interpret: bool,
+def match_pallas_stream(q, dbt, db_mask, *, metric: str, interpret: bool,
                         kblock: int = None):
     """Streaming/tiled-database matcher: q [NQ, D] (NQ a QBLOCK multiple),
-    db [NK, D] (NK a KBLOCK multiple — pad rows masked invalid),
+    dbt [D, NK] (NK a KBLOCK multiple — pad rows masked invalid),
     db_mask [1, NK] int32 -> (best [NQ], second [NQ], idx [NQ]).
 
     VMEM working set is ~2 DB chunks + 1 query block + the chunk
@@ -305,7 +325,7 @@ def match_pallas_stream(q, db, db_mask, *, metric: str, interpret: bool,
     NK is bounded by HBM, not by the 12 MiB VMEM budget that gates the
     resident kernel."""
     nq, d = q.shape
-    nk = db.shape[0]
+    nk = dbt.shape[1]
     kblock = kblock_for(metric) if kblock is None else kblock
     dist_dt = jnp.int32 if metric == "hamming" else jnp.float32
     grid = (nq // QBLOCK, nk // kblock)
@@ -315,12 +335,11 @@ def match_pallas_stream(q, db, db_mask, *, metric: str, interpret: bool,
         kern,
         grid=grid,
         in_specs=[pl.BlockSpec((QBLOCK, d), lambda i, k: (i, 0)),
-                  pl.BlockSpec((kblock, d), lambda i, k: (k, 0)),
+                  pl.BlockSpec((d, kblock), lambda i, k: (0, k)),
                   pl.BlockSpec((1, kblock), lambda i, k: (0, k))],
-        out_specs=[pl.BlockSpec((1, QBLOCK), lambda i, k: (i, 0))] * 3,
-        out_shape=[jax.ShapeDtypeStruct((grid[0], QBLOCK), dist_dt),
-                   jax.ShapeDtypeStruct((grid[0], QBLOCK), dist_dt),
-                   jax.ShapeDtypeStruct((grid[0], QBLOCK), jnp.int32)],
+        out_specs=[pl.BlockSpec((QBLOCK, 1), lambda i, k: (i, 0))] * 3,
+        out_shape=_best2_out_shapes(nq, dist_dt),
         interpret=interpret,
-    )(q, db, db_mask)
+        name=f"match_stream_{metric}",
+    )(q, dbt, db_mask)
     return tuple(o.reshape(-1) for o in outs)

@@ -1,9 +1,9 @@
 """jit'd public wrappers for the Pallas kernels.
 
 Handle host-side reflect padding (so kernel slicing is 'valid'), lane-dim
-alignment to 128 multiples, [H,W] vs [N,H,W] rank, and the interpret-mode
-fallback on CPU (this container validates kernels in interpret mode; on a
-real TPU set ``interpret=False``/default).
+alignment to 128 multiples and [H,W] vs [N,H,W] rank.  ``interpret=None``
+(the default) picks by backend: compiled Mosaic kernels on a TPU, the
+Pallas interpreter anywhere else (CPU tests validate numerics there).
 """
 from __future__ import annotations
 
@@ -25,7 +25,8 @@ from repro.kernels import scalespace as _scalespace
 
 LANE = 128
 # VMEM budget for the fused scale-space kernel: leave headroom below the
-# ~16 MiB v5e per-core VMEM for double-buffered DMA + compiler spill
+# TPU compiler's default 16 MiB scoped-VMEM limit per kernel (a v5e core
+# has 128 MiB of VMEM) for double-buffered DMA + compiler spill
 # (DESIGN.md §6).
 VMEM_BUDGET_BYTES = 12 * 2 ** 20
 
@@ -131,13 +132,13 @@ MATCH_QBLOCK = _matcher.QBLOCK
 def matcher_vmem_bytes(nk: int, d: int, metric: str = "l2") -> int:
     """Working-set estimate for the matcher kernel: the VMEM-resident
     database slab + one query block + the per-chunk distance temporaries
-    (Hamming also holds the [Q, C, W] XOR/popcount intermediate).  See
-    DESIGN.md §7 for the budget table."""
+    (Hamming: the running sum and the XOR/popcount temporaries of one
+    word, each [Q, C]).  See DESIGN.md §7 for the budget table."""
     kc = min(_matcher.kchunk_for(metric), nk)
     db = nk * d * 4
     q = MATCH_QBLOCK * d * 4
     if metric == "hamming":
-        tmp = MATCH_QBLOCK * kc * (2 * d + 2) * 4
+        tmp = MATCH_QBLOCK * kc * 4 * 4
     else:
         tmp = MATCH_QBLOCK * kc * 3 * 4 + 2 * nk * 4
     return db + q + tmp + 6 * MATCH_QBLOCK * 4
@@ -196,15 +197,16 @@ def _match_impl(queries, db, db_valid, *, metric: str, path: str,
     qp = jnp.pad(queries, ((0, pad_q), (0, 0))) if pad_q else queries
     mask = db_valid.astype(jnp.int32)
     if path == "pallas_resident":
-        best, second, idx = _matcher.match_pallas(
-            qp, db, mask[None, :], metric=metric, interpret=interpret)
+        kernel, step = _matcher.match_pallas, _matcher.kchunk_for(metric)
     else:                                      # pallas_stream
-        pad_k = (-nk) % _matcher.kblock_for(metric)
-        if pad_k:                              # pad rows masked invalid
-            db = jnp.pad(db, ((0, pad_k), (0, 0)))
-            mask = jnp.pad(mask, (0, pad_k))
-        best, second, idx = _matcher.match_pallas_stream(
-            qp, db, mask[None, :], metric=metric, interpret=interpret)
+        kernel, step = (_matcher.match_pallas_stream,
+                        _matcher.kblock_for(metric))
+    pad_k = (-nk) % step
+    if pad_k:                                  # pad rows masked invalid
+        db = jnp.pad(db, ((0, pad_k), (0, 0)))
+        mask = jnp.pad(mask, (0, pad_k))
+    best, second, idx = kernel(qp, db.T, mask[None, :], metric=metric,
+                               interpret=interpret)
     return best[:nq], second[:nq], idx[:nq]
 
 

@@ -22,8 +22,9 @@ level instead, so the two agree only beyond the cumulative-radius band
 (DESIGN.md §6).
 
 Grid: one program per tile.  VMEM working set is ~(n_scales + 4) padded
-slabs; the ops.py wrapper checks it against the ~16 MiB v5e budget and the
-dispatcher falls back to the streaming jnp path for oversized tiles.
+slabs; the ops.py wrapper checks it against a 12 MiB budget (under the
+TPU compiler's default 16 MiB scoped-VMEM limit) and the dispatcher takes
+the streaming jnp path for oversized tiles.
 """
 from __future__ import annotations
 
@@ -116,4 +117,5 @@ def scalespace_pallas(x_padded, *, taps_list, h: int, w: int,
         out_shape=[jax.ShapeDtypeStruct((n, h, w), jnp.float32),
                    jax.ShapeDtypeStruct((n, h, w), jnp.float32)],
         interpret=interpret,
+        name="scalespace_octave",
     )(x_padded)

@@ -101,8 +101,6 @@ def model_flops(n_params: int, n_tokens: int, kind: str = "train") -> float:
 
 def cost_analysis_terms(compiled) -> Dict[str, float]:
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):   # older jax returns [dict]
-        ca = ca[0]
     flops = float(ca.get("flops", 0.0))
     bytes_accessed = float(ca.get("bytes accessed", 0.0))
     return {"hlo_flops": flops, "hlo_bytes": bytes_accessed}

@@ -15,6 +15,7 @@ from repro.core.bundle import BundleStore, bundle_scenes
 from repro.core.engine import normalize_algorithms
 from repro.core.job import DifetJob
 from repro.data.landsat import synthetic_scene
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def build_store(store_path, n_scenes, scene_hw, cfg, scenes_per_bundle=1,
@@ -70,6 +71,7 @@ def main(argv=None):
     ap.add_argument("--fail-after", type=int, default=None,
                     help="simulate worker failure after N bundles")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     # canonicalize: strip whitespace, drop repeats (first occurrence wins),
     # reject unknown names with the valid choices listed

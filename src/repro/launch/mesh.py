@@ -7,19 +7,13 @@ locked on first jax init, and smoke tests must keep seeing 1 CPU device.
 from __future__ import annotations
 
 import jax
-
-try:                                        # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:                         # older jax: axes are Auto-typed
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 chips per pod ('data' x 'model'); 2 pods stack a 'pod' axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 
@@ -28,7 +22,5 @@ def make_host_mesh():
     """Degenerate mesh over however many devices the host actually has —
     used by smoke tests and the CPU examples."""
     n = len(jax.devices())
-    if AxisType is None:
-        return jax.make_mesh((n, 1), ("data", "model"))
     return jax.make_mesh((n, 1), ("data", "model"),
                          axis_types=(AxisType.Auto, AxisType.Auto))

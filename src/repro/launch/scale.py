@@ -40,6 +40,7 @@ from repro.core.engine import extract_features_multi, normalize_algorithms
 from repro.data.landsat import BandSceneReader, write_synthetic_scene_set
 from repro.data.pipeline import (Prefetcher, batch_slices, count_batches,
                                  iter_tile_batches)
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def build_scene_set(root, n_scenes: int, scene_hw: Tuple[int, int]):
@@ -214,6 +215,7 @@ def main(argv=None):
                     help="tiny CI mode: 2 scenes, workers 1,2; exits "
                          "non-zero unless every sweep is bit-exact")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.smoke:
         args.scenes, args.scene_size = 2, 160
         args.tile, args.halo, args.batch_tiles = 64, 16, 4

@@ -34,6 +34,7 @@ import numpy as np
 
 from repro.configs.difet_paper import DifetConfig
 from repro.core.engine import normalize_algorithms
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import FeatureService, ServeConfig, ServiceOverloaded
 from repro.serve.trace import TraceConfig, make_trace, tile_pool
 
@@ -231,6 +232,7 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true",
                     help="CI smoke mode: assertions + non-zero exit")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.smoke:
         raise SystemExit(smoke(args))

@@ -24,6 +24,7 @@ from repro.core import mosaic
 from repro.core.bundle import BundleStore, bundle_scenes
 from repro.core.job import DifetJob
 from repro.data.landsat import synthetic_scene
+from repro.launch.compile_cache import enable_compile_cache
 
 DESCRIPTOR_ALGORITHMS = ("sift", "surf", "brief", "orb")
 
@@ -110,6 +111,7 @@ def main(argv=None):
     ap.add_argument("--fail-after", type=int, default=None,
                     help="simulate worker failure after N match chunks")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     # lower FAST threshold than the extraction default: registration wants
     # *many* verifiable corners, not just the strongest (Table-2) ones

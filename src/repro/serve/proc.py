@@ -239,6 +239,16 @@ def _worker_main(argv=None) -> int:
 
 # -- parent-side proxy -------------------------------------------------------
 
+def _parent_backend() -> Optional[str]:
+    """This process's default JAX platform if its backends are already
+    initialized, else None (asking would initialize them — and take the
+    chip)."""
+    from jax._src import xla_bridge
+    if not xla_bridge.backends_are_initialized():
+        return None
+    return xla_bridge.default_backend()
+
+
 class ProcHandle:
     """Parent-side handle for one request to a process replica; mirrors
     `serve/api.py::ResponseHandle` (``done()``/``result()``) and adds
@@ -345,7 +355,19 @@ class ProcReplicaClient:
         """Launch the worker process (``python -m repro.serve.proc``)
         with its mailbox under ``root``; returns immediately — pair with
         :meth:`wait_ready`.  stdout/stderr land in
-        ``<root>/worker.log``."""
+        ``<root>/worker.log``.
+
+        The worker inherits this process's environment, and with it its
+        platform.  A TPU chip belongs to one process at a time, so once
+        this process has started a TPU backend no worker can reach the
+        chip: that raises here instead of starting a worker that would
+        hang on the chip's lock."""
+        if _parent_backend() == "tpu":
+            raise RuntimeError(
+                f"cannot spawn worker {name!r}: this process already holds "
+                "the TPU (its JAX backend is initialized), and a chip "
+                "serves one process at a time — spawn workers before the "
+                "parent touches JAX, or run thread replicas in-process")
         root = Path(root)
         root.mkdir(parents=True, exist_ok=True)
         cfg_path = root / "serve_config.json"
@@ -354,7 +376,6 @@ class ProcReplicaClient:
         env = dict(os.environ)
         env["PYTHONPATH"] = (f"{src_dir}{os.pathsep}{env['PYTHONPATH']}"
                              if env.get("PYTHONPATH") else str(src_dir))
-        env.setdefault("JAX_PLATFORMS", "cpu")
         cmd = [sys.executable, "-m", "repro.serve.proc",
                "--name", name, "--dir", str(root),
                "--lease-dir", str(lease_dir),

@@ -13,6 +13,11 @@ import jax  # noqa: E402
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
+# The drivers' main() turns JAX's persistent compilation cache on
+# (`launch/compile_cache.py`); tests that call a main() keep it off, so a
+# test run writes nothing to the checkout's .jax_cache/.
+jax.config.update("jax_enable_compilation_cache", False)
+
 # Hypothesis determinism: explicit profiles with deadlines disabled (the
 # chaos/fleet tests share CI machines with compile-heavy neighbours, so
 # wall-clock deadlines flake) and derandomized example generation — the

@@ -68,6 +68,33 @@ def test_descriptor_shapes_and_norms():
     assert np.asarray(r2["top_desc"]).shape[-1] == 8   # 256 bits
 
 
+def test_descriptor_histogram_adds_in_raster_order():
+    """SIFT histograms accumulate each patch's pixels in raster order, as
+    sequential float32 adds — the same on every backend and batch size."""
+    from repro.core.descriptors import _histogram
+    rng = np.random.RandomState(0)
+    bins = rng.randint(0, 36, (3, 16, 16)).astype(np.int32)
+    w = rng.rand(3, 16, 16).astype(np.float32)
+    want = np.zeros((3, 36), np.float32)
+    for k in range(3):
+        for b, x in zip(bins[k].ravel(), w[k].ravel()):
+            want[k, b] = np.float32(want[k, b] + x)
+    got = jax.jit(_histogram, static_argnums=2)(bins, w, 36)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def test_integral_image_is_batch_invariant():
+    from repro.core.pyramid import integral_image
+    img = np.random.RandomState(1).rand(4, 70, 90).astype(np.float32)
+    ii = np.asarray(jax.jit(integral_image)(img))
+    assert ii.shape == (4, 71, 91) and not ii[:, 0].any() and not ii[:, :, 0].any()
+    np.testing.assert_allclose(ii[:, 1:, 1:],
+                               img.astype(np.float64).cumsum(1).cumsum(2),
+                               rtol=1e-6)
+    one = np.asarray(jax.jit(integral_image)(img[2:3]))
+    np.testing.assert_array_equal(one, ii[2:3])
+
+
 def test_extract_features_fused_equals_seed():
     """The fused SIFT path and the batched-gather patch extraction must not
     change extraction results: compare `sift`/`brief`/`orb` against the

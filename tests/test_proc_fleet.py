@@ -138,6 +138,46 @@ def test_serve_config_wire_roundtrip():
     assert serve_config_from_json(wire) == cfg
 
 
+def test_spawn_leaves_the_platform_to_the_environment(tmp_path,
+                                                     monkeypatch):
+    """The worker inherits the parent's environment as it is: spawn no
+    longer writes JAX_PLATFORMS (which sent every replica to the CPU)."""
+    from repro.serve import proc
+    seen = []
+
+    class FakePopen:
+        pid = 0
+
+        def __init__(self, cmd, stdout=None, stderr=None, env=None):
+            seen.append(env)
+
+    monkeypatch.setattr(proc.subprocess, "Popen", FakePopen)
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    ProcReplicaClient.spawn("w", tmp_path / "a", serve_cfg(), tmp_path / "l")
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    ProcReplicaClient.spawn("w", tmp_path / "b", serve_cfg(), tmp_path / "l")
+    assert "JAX_PLATFORMS" not in seen[0]
+    assert seen[1]["JAX_PLATFORMS"] == "cpu"
+
+
+def test_spawn_refuses_once_the_parent_holds_the_tpu(tmp_path, monkeypatch):
+    """A chip serves one process: a parent with a TPU backend must not
+    start a worker that would hang on the chip's lock."""
+    from repro.serve import proc
+    monkeypatch.setattr(proc, "_parent_backend", lambda: "tpu")
+    monkeypatch.setattr(proc.subprocess, "Popen", lambda *a, **k: (
+        pytest.fail("a worker was started")))
+    with pytest.raises(RuntimeError, match="already holds the TPU"):
+        ProcReplicaClient.spawn("w", tmp_path / "a", serve_cfg(),
+                                tmp_path / "l")
+
+
+def test_parent_backend_is_none_or_the_initialized_platform():
+    from repro.serve import proc
+    import jax
+    assert proc._parent_backend() in (None, jax.default_backend())
+
+
 def test_chaos_plan_file_lifecycle(tmp_path):
     assert read_plan(tmp_path) == ChaosPlan()          # absent: all off
     write_plan(tmp_path, ChaosPlan(heartbeat_stall_s=2.0,
