@@ -50,7 +50,7 @@ def _harris_resp(img, cfg, use_pallas):
     return D.harris_response(img, k=cfg.harris_k, use_pallas=use_pallas)
 
 
-def _shi_resp(img, cfg, use_pallas):
+def _shi_tomasi_resp(img, cfg, use_pallas):
     return D.shi_tomasi_response(img, use_pallas=use_pallas)
 
 
@@ -80,7 +80,7 @@ def _surf_resp(img, cfg, use_pallas):
 ALGORITHMS: Dict[str, AlgorithmSpec] = {
     "harris": AlgorithmSpec(_harris_resp, None,
                             lambda c: c.harris_threshold * 1e-4),
-    "shi_tomasi": AlgorithmSpec(_shi_resp, None,
+    "shi_tomasi": AlgorithmSpec(_shi_tomasi_resp, None,
                                 lambda c: c.shi_tomasi_threshold * 1e-2),
     "sift": AlgorithmSpec(_sift_resp, DS.sift_descriptors,
                           lambda c: c.sift_contrast_threshold
@@ -117,27 +117,47 @@ def normalize_algorithms(spec) -> tuple:
     return tuple(out)
 
 
-def _select_and_describe(spec: AlgorithmSpec, cfg: DifetConfig, tile, header,
+def _response(spec: AlgorithmSpec, cfg: DifetConfig, tile, use_pallas):
+    """The dense response map under the named scope
+    ``difet.response.<fn>`` (``_fast_resp`` -> ``difet.response.fast``): one
+    scope per distinct response function, so a shared map is named once."""
+    fn = spec.response
+    with jax.named_scope(
+            "difet.response." + fn.__name__.strip("_").removesuffix("_resp")):
+        return fn(tile, cfg, use_pallas)
+
+
+def _select_and_describe(algorithm: str, cfg: DifetConfig, tile, header,
                          resp):
     """NMS → capacity-K selection → describe, given a precomputed response
     map.  Factored out of ``extract_tile`` so algorithms sharing a response
-    (fast/brief/orb all use the FAST score) compute it once."""
+    (fast/brief/orb all use the FAST score) compute it once.  Each stage
+    runs under the named scope ``difet.<algorithm>/<stage>`` (``nms``,
+    ``topk``, ``describe``), which names its device operations in a
+    profiler trace; scopes change op metadata only, never the program."""
+    spec = ALGORITHMS[algorithm]
     thr = spec.threshold(cfg)
-    valid_h, valid_w = header[3], header[4]
-    not_pad = header[5] == 0
-    mask = nms.interior_mask(resp.shape, cfg.halo, valid_h, valid_w) & not_pad
-    count = nms.count_above(resp, thr, mask)
-    resp_nms = nms.nms3x3(resp)
     k = cfg.max_keypoints_per_tile
-    ys, xs, scores, valid = nms.topk_keypoints(resp_nms, k, thr, mask)
-    out = {"count": count, "scores": scores, "valid": valid}
-    # global scene coordinates (interior-relative)
-    out["ys"] = header[1] * cfg.tile + (ys - cfg.halo)
-    out["xs"] = header[2] * cfg.tile + (xs - cfg.halo)
-    if spec.describe is not None:
-        desc = spec.describe(tile, ys, xs)
-        out["desc"] = jnp.where(valid[:, None], desc,
-                                jnp.zeros_like(desc))
+    with jax.named_scope(f"difet.{algorithm}"):
+        with jax.named_scope("nms"):
+            valid_h, valid_w = header[3], header[4]
+            not_pad = header[5] == 0
+            mask = nms.interior_mask(resp.shape, cfg.halo, valid_h,
+                                     valid_w) & not_pad
+            count = nms.count_above(resp, thr, mask)
+            resp_nms = nms.nms3x3(resp)
+        with jax.named_scope("topk"):
+            ys, xs, scores, valid = nms.topk_keypoints(resp_nms, k, thr,
+                                                       mask)
+            out = {"count": count, "scores": scores, "valid": valid}
+            # global scene coordinates (interior-relative)
+            out["ys"] = header[1] * cfg.tile + (ys - cfg.halo)
+            out["xs"] = header[2] * cfg.tile + (xs - cfg.halo)
+        if spec.describe is not None:
+            with jax.named_scope("describe"):
+                desc = spec.describe(tile, ys, xs)
+                out["desc"] = jnp.where(valid[:, None], desc,
+                                        jnp.zeros_like(desc))
     return out
 
 
@@ -146,9 +166,8 @@ def extract_tile(algorithm: str, cfg: DifetConfig, tile, header,
     """The DIFET 'map function' for one tile (cf. the paper's pseudo-code:
     convert → grayscale → detect → describe → emit).  Returns a dict of
     fixed-shape features."""
-    spec = ALGORITHMS[algorithm]
-    resp = spec.response(tile, cfg, use_pallas)
-    return _select_and_describe(spec, cfg, tile, header, resp)
+    resp = _response(ALGORITHMS[algorithm], cfg, tile, use_pallas)
+    return _select_and_describe(algorithm, cfg, tile, header, resp)
 
 
 def extract_tile_multi(algorithms, cfg: DifetConfig, tile, header,
@@ -161,41 +180,44 @@ def extract_tile_multi(algorithms, cfg: DifetConfig, tile, header,
     for alg in algorithms:
         spec = ALGORITHMS[alg]
         if spec.response not in resp_cache:
-            resp_cache[spec.response] = spec.response(tile, cfg, use_pallas)
-        out[alg] = _select_and_describe(spec, cfg, tile, header,
+            resp_cache[spec.response] = _response(spec, cfg, tile,
+                                                  use_pallas)
+        out[alg] = _select_and_describe(alg, cfg, tile, header,
                                         resp_cache[spec.response])
     return out
 
 
-def _reduce_features(per_tile):
-    """The reduce: total count all-reduce + global top-K merge."""
-    total = per_tile["count"].sum()
-    t, k = per_tile["scores"].shape
-    flat_scores = per_tile["scores"].reshape(t * k)
-    flat_valid = per_tile["valid"].reshape(t * k)
-    masked = jnp.where(flat_valid, flat_scores, -jnp.inf)
-    top_scores, idx = jax.lax.top_k(masked, min(k * 4, t * k))
-    top_valid = jnp.isfinite(top_scores)
+def _reduce_features(algorithm: str, per_tile):
+    """The reduce: total count all-reduce + global top-K merge, under the
+    named scope ``difet.<algorithm>/reduce``."""
+    with jax.named_scope(f"difet.{algorithm}"), jax.named_scope("reduce"):
+        total = per_tile["count"].sum()
+        t, k = per_tile["scores"].shape
+        flat_scores = per_tile["scores"].reshape(t * k)
+        flat_valid = per_tile["valid"].reshape(t * k)
+        masked = jnp.where(flat_valid, flat_scores, -jnp.inf)
+        top_scores, idx = jax.lax.top_k(masked, min(k * 4, t * k))
+        top_valid = jnp.isfinite(top_scores)
 
-    def gather(a):
-        # invalid picks tie at -inf in whatever order the backend's top_k
-        # leaves them: zero their payload so it never depends on that order
-        g = jnp.take(a.reshape(t * k, *a.shape[2:]), idx, axis=0)
-        return jnp.where(top_valid.reshape(-1, *[1] * (g.ndim - 1)), g,
-                         jnp.zeros_like(g))
+        def gather(a):
+            # invalid picks tie at -inf in whatever order the backend's top_k
+            # leaves them: zero their payload so it never depends on that order
+            g = jnp.take(a.reshape(t * k, *a.shape[2:]), idx, axis=0)
+            return jnp.where(top_valid.reshape(-1, *[1] * (g.ndim - 1)), g,
+                             jnp.zeros_like(g))
 
-    result = {
-        "total_count": total,
-        "per_tile_count": per_tile["count"],
-        "top_scores": jnp.where(top_valid, top_scores, 0.0),
-        "top_ys": gather(per_tile["ys"]),
-        "top_xs": gather(per_tile["xs"]),
-        "top_valid": gather(per_tile["valid"]),
-        "keypoint_count": per_tile["valid"].sum(),
-    }
-    if "desc" in per_tile:
-        result["top_desc"] = gather(per_tile["desc"])
-    return result
+        result = {
+            "total_count": total,
+            "per_tile_count": per_tile["count"],
+            "top_scores": jnp.where(top_valid, top_scores, 0.0),
+            "top_ys": gather(per_tile["ys"]),
+            "top_xs": gather(per_tile["xs"]),
+            "top_valid": gather(per_tile["valid"]),
+            "keypoint_count": per_tile["valid"].sum(),
+        }
+        if "desc" in per_tile:
+            result["top_desc"] = gather(per_tile["desc"])
+        return result
 
 
 def extract_features(bundle_tiles, bundle_headers, algorithm: str,
@@ -205,7 +227,7 @@ def extract_features(bundle_tiles, bundle_headers, algorithm: str,
         functools.partial(extract_tile, algorithm, cfg,
                           use_pallas=use_pallas))(
         bundle_tiles, bundle_headers)
-    return _reduce_features(per_tile)
+    return _reduce_features(algorithm, per_tile)
 
 
 def map_tiles_multi(bundle_tiles, bundle_headers, algorithms,
@@ -221,7 +243,7 @@ def map_tiles_multi(bundle_tiles, bundle_headers, algorithms,
 
 def reduce_features_multi(per_tile):
     """The reduce of `map_tiles_multi`'s output, per algorithm."""
-    return {alg: _reduce_features(r) for alg, r in per_tile.items()}
+    return {alg: _reduce_features(alg, r) for alg, r in per_tile.items()}
 
 
 def extract_features_multi(bundle_tiles, bundle_headers, algorithms,
@@ -254,11 +276,12 @@ def extract_request_features(bundle_tiles, bundle_headers, algorithms,
                           use_pallas=use_pallas))(
         bundle_tiles, bundle_headers)
 
-    def _single(tree):
+    def _single(alg, tree):
         return _reduce_features(
-            jax.tree_util.tree_map(lambda a: a[None], tree))
+            alg, jax.tree_util.tree_map(lambda a: a[None], tree))
 
-    return {alg: jax.vmap(_single)(per_tile[alg]) for alg in algorithms}
+    return {alg: jax.vmap(functools.partial(_single, alg))(per_tile[alg])
+            for alg in algorithms}
 
 
 def make_serve_step(algorithms, cfg: DifetConfig, use_pallas: bool = False):

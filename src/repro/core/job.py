@@ -40,6 +40,7 @@ from repro.core.bundle import BundleStore, TileBundle
 from repro.core.engine import (extract_features, extract_features_multi,
                                map_tiles_multi, reduce_features_multi)
 from repro.obs import metrics as obs_metrics
+from repro.obs import trace as obs_trace
 
 
 @dataclasses.dataclass
@@ -244,6 +245,8 @@ class ManifestJob:
             progress: Optional[Callable[[str], None]] = None,
             worker_id: Optional[str] = None) -> Dict:
         """Process remaining items in manifest order; returns `summary()`.
+        Each item runs in a ``bundle`` span (layer ``job``, `obs/trace.py`)
+        around ``process`` and its manifest commit (``commit``).
 
         Args:
             simulate_failure_after: raise after N items (fault-tolerance
@@ -267,13 +270,16 @@ class ManifestJob:
                     continue
                 if not self.leases.acquire(name, worker_id):
                     continue                    # leased by a live worker
-            self.process(name)
-            self.manifest.done[name] = True
+            with obs_trace.span("bundle", "job", item=name):
+                self.process(name)
+                self.manifest.done[name] = True
+                with obs_trace.span("commit", "job"):
+                    if worker_id is not None:
+                        self._commit_merged()
+                    else:
+                        self._commit(self.manifest)
             if worker_id is not None:
-                self._commit_merged()
                 self.leases.release(name, worker_id)
-            else:
-                self._commit(self.manifest)
             processed += 1
             if progress:
                 progress(name)
@@ -403,7 +409,9 @@ class DifetJob(ManifestJob):
             b = TileBundle(np.asarray(tiles), np.asarray(headers),
                            cfg).pad_to(n + pad)
             out = self._sharded_fn(b.tiles.shape, cfg)(b.tiles, b.headers)
-            out = jax.device_get(out)
+            # waits for the device, then one host transfer
+            with obs_trace.span("fetch", "job"):
+                out = jax.device_get(out)
             return {alg: self._slice_result(r, n,
                                             cfg.max_keypoints_per_tile)
                     for alg, r in out.items()}
@@ -417,16 +425,25 @@ class DifetJob(ManifestJob):
     def process(self, name: str) -> None:
         """Extract one bundle: split into shards, extract each (device-
         sharded when a mesh is set), merge shard partials, and commit one
-        ``<name>.<algorithm>`` result per algorithm to the store."""
-        bundle = self.store.get(name)
+        ``<name>.<algorithm>`` result per algorithm to the store.  Spans
+        (layer ``job``): ``get`` (the store read), ``extract`` per shard
+        (on a mesh with its ``fetch``, the wait for the device and the
+        transfer), ``merge`` and ``put`` (the result writes)."""
+        with obs_trace.span("get", "job"):
+            bundle = self.store.get(name)
         partials: Dict[str, List[Dict]] = {}
         for shard in self._shards(bundle):
-            r = self._extract(shard.tiles, shard.headers, bundle.cfg)
-            for alg, res in r.items():
-                partials.setdefault(alg, []).append(
-                    {k: np.asarray(v) for k, v in res.items()})
-        for alg, parts in partials.items():
-            self.store.put_result(f"{name}.{alg}", self._merge(parts))
+            with obs_trace.span("extract", "job"):
+                r = self._extract(shard.tiles, shard.headers, bundle.cfg)
+                for alg, res in r.items():
+                    partials.setdefault(alg, []).append(
+                        {k: np.asarray(v) for k, v in res.items()})
+        with obs_trace.span("merge", "job"):
+            merged = {alg: self._merge(parts)
+                      for alg, parts in partials.items()}
+        with obs_trace.span("put", "job"):
+            for alg, res in merged.items():
+                self.store.put_result(f"{name}.{alg}", res)
 
     @staticmethod
     def _merge(partials: List[Dict]) -> Dict:

@@ -8,19 +8,28 @@ its request passed router admission — so one request's whole journey
 ``readmit`` hop after ``ReplicaDied``) shares one id and renders as one
 lane in ``chrome://tracing`` (`repro/obs/export.py`).
 
-The recorder is process-global and defaults to :class:`NoopRecorder`:
-every instrumentation site guards on ``enabled()`` before touching a
-clock, so the disabled cost is one attribute read per site — measurably
-free (the bench_serve/bench_fleet throughput gates run with the no-op
-recorder and must stay green).  :class:`FlightRecorder` keeps the last N
-finished spans in a ring buffer and can dump them as Chrome-trace JSON
-on demand or on a crash/shed trigger (``dump_on``) — the "what was the
-fleet doing right before it died" artifact.
+One span system, two sinks:
 
-Timestamps are ``time.monotonic()`` floats; cross-thread ordering within
-a process is meaningful (Linux CLOCK_MONOTONIC), and the exporter
-rebases to trace start.  Instrumentation only *observes* — it never
-changes batch formation, routing, or numerics, so traced runs stay
+* every :func:`span` also opens a ``jax.profiler.TraceAnnotation`` named
+  ``difet.<layer>.<name>`` around its block, recorder or not.  With no
+  profiler session active that is one TraceMe enter and exit (an empty
+  ``span()`` took 2.0 us on a TPU v5e host, the TraceMe 0.4 us of it;
+  docs/observability.md); with a session active (``jax.profiler``) the
+  span lands in the ``.xplane.pb`` on the device trace's clock, so an
+  idle stretch of the device can be read against what the host thread
+  that drives it was doing.
+* the flight recorder.  It is process-global and defaults to
+  :class:`NoopRecorder`: every recording site guards on ``enabled()``
+  before touching a clock, so with it off a span costs the TraceMe and
+  one attribute read.  :class:`FlightRecorder` keeps the last N finished
+  spans in a ring buffer and can dump them as Chrome-trace JSON on demand
+  or on a crash/shed trigger (``dump_on``) — the "what was the fleet
+  doing right before it died" artifact.
+
+Recorder timestamps are ``time.monotonic()`` floats; cross-thread
+ordering within a process is meaningful (Linux CLOCK_MONOTONIC), and the
+exporter rebases to trace start.  Instrumentation only *observes* — it
+never changes batch formation, routing, or numerics, so traced runs stay
 bit-identical to untraced ones (tested).
 """
 from __future__ import annotations
@@ -34,6 +43,8 @@ import threading
 import time
 from collections import deque
 from typing import Dict, Iterator, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["Span", "NoopRecorder", "FlightRecorder", "get_recorder",
            "set_recorder", "enabled", "new_trace_id", "new_span_id",
@@ -221,7 +232,10 @@ def emit_span(name: str, layer: str, t0: float, t1: float, *,
               span_id: Optional[str] = None, **attrs) -> Optional[str]:
     """Record an already-timed span (the scheduler computes queue-wait
     from stamps it takes anyway; no nested timing needed).  Returns the
-    span id, or None when tracing is off."""
+    span id, or None when tracing is off.  Recorder only: a profiler
+    TraceMe cannot be back-dated, so spans recorded after the fact never
+    reach a ``jax.profiler`` trace — use :func:`span` around the work for
+    that."""
     rec = _RECORDER
     if not rec.enabled:
         return None
@@ -238,17 +252,21 @@ def emit_span(name: str, layer: str, t0: float, t1: float, *,
 
 @contextlib.contextmanager
 def span(name: str, layer: str, *, trace_id: Optional[str] = None,
-         parent_id: str = "", **attrs) -> Iterator[None]:
-    """Time a block and record it as one span.  When tracing is off this
-    is one boolean check and a bare yield — the zero-cost-when-disabled
-    contract the serving hot paths rely on."""
-    rec = _RECORDER
-    if not rec.enabled:
-        yield
-        return
-    t0 = time.monotonic()
-    try:
-        yield
-    finally:
-        emit_span(name, layer, t0, time.monotonic(), trace_id=trace_id,
-                  parent_id=parent_id, **attrs)
+         parent_id: str = "", **attrs) -> Iterator[Optional[str]]:
+    """Time a block as one span: a ``difet.<layer>.<name>`` profiler
+    TraceMe around it always, and a recorded :class:`Span` when tracing
+    is on.  Yields the recorded span's id (for children's ``parent_id``),
+    or None when tracing is off.  With tracing off and no profiler
+    session this costs one TraceMe enter/exit and one boolean check."""
+    with TraceAnnotation(f"difet.{layer}.{name}"):
+        rec = _RECORDER
+        if not rec.enabled:
+            yield None
+            return
+        sid = new_span_id()
+        t0 = time.monotonic()
+        try:
+            yield sid
+        finally:
+            emit_span(name, layer, t0, time.monotonic(), trace_id=trace_id,
+                      parent_id=parent_id, span_id=sid, **attrs)

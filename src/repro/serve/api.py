@@ -168,8 +168,9 @@ class ResponseHandle:
         every tile of the request, not per tile.
 
         ``timing["completed_at"]`` is when the request's *work* finished —
-        the latest device-batch completion stamp across its tiles (a
-        fully-cached request completes at submit time) — NOT when
+        the latest of its tiles' stamps, each taken by the runner once the
+        tile's results were sliced and cached (a fully-cached request
+        completes at submit time) — NOT when
         ``result()`` happened to be called.  An open-loop client that
         drains handles in submit order therefore measures true service
         latency, not its own drain position (``latency_s`` used to be
@@ -396,77 +397,79 @@ class FeatureService:
     def _run_batch_locked(self, bucket, algorithms, items) -> None:
         t_start = time.monotonic()
         tracing = obs_trace.enabled()
-        if tracing:
-            # queue-wait spans: enqueue → batch formation, one per item,
-            # carrying the item's trace id (stamps already taken — no
-            # extra clock reads on the untraced path)
+        # three spans, one after the other, on the runner thread:
+        # ``batch.scatter`` (canvas fill), ``kernel.device_step`` (program
+        # call + one host transfer) and ``batch.deliver`` (freeze, slice,
+        # cache, resolve)
+        with obs_trace.span("scatter", "batch", trace_id="",
+                            replica=self.name, bucket=bucket):
             for it in items:
-                obs_trace.emit_span("queue_wait", "scheduler",
-                                    it.enqueued_at, t_start,
-                                    trace_id=it.trace_id,
-                                    replica=self.name, bucket=bucket)
-        # per-bucket scratch canvas, reused across steps (runner thread is
-        # the only writer).  Rows beyond the batch keep stale-but-finite
-        # tile data; their headers are re-marked pad, so the engine masks
-        # them out — only the zeroing is skipped.
-        canvas = self._canvases.get(bucket)
-        if canvas is None:
-            canvas = self._canvases[bucket] = \
-                self.compile_cache.empty_batch(bucket)
-        tiles, headers = canvas
-        headers[:, :] = 0
-        headers[:, 5] = 1
-        for i, it in enumerate(items):
-            tiles[i] = it.tile
-            headers[i] = it.header
-        fn = self.compile_cache.get(bucket, algorithms)
+                # enqueue → batch formation, stamped by the scheduler
+                self._m_queue_s.observe(it.taken_at - it.enqueued_at)
+                if tracing:
+                    # one queue-wait span per item, carrying its trace id
+                    obs_trace.emit_span("queue_wait", "scheduler",
+                                        it.enqueued_at, it.taken_at,
+                                        trace_id=it.trace_id,
+                                        replica=self.name, bucket=bucket)
+            # per-bucket scratch canvas, reused across steps (runner thread
+            # is the only writer).  Rows beyond the batch keep
+            # stale-but-finite tile data; their headers are re-marked pad,
+            # so the engine masks them out — only the zeroing is skipped.
+            canvas = self._canvases.get(bucket)
+            if canvas is None:
+                canvas = self._canvases[bucket] = \
+                    self.compile_cache.empty_batch(bucket)
+            tiles, headers = canvas
+            headers[:, :] = 0
+            headers[:, 5] = 1
+            for i, it in enumerate(items):
+                tiles[i] = it.tile
+                headers[i] = it.header
+            fn = self.compile_cache.get(bucket, algorithms)
         t_kernel = time.monotonic()
-        out = jax.device_get(fn(tiles, headers))   # one host transfer
-        t_kernel_done = time.monotonic()
-        self._m_step_s.observe(t_kernel_done - t_kernel)
-        batch_span = None
-        if tracing:
-            batch_span = obs_trace.emit_span(
-                "device_step", "kernel", t_kernel, t_kernel_done,
-                trace_id="", replica=self.name, bucket=bucket,
-                batch_size=len(items), algorithms=",".join(algorithms))
-        for res in out.values():
-            for v in res.values():
-                v.setflags(write=False)            # responses are read-only
-        caching = self.cache.capacity > 0
-        # service-time stamp: the device step for this batch is done NOW.
-        # It rides in the future payload so ResponseHandle can report the
-        # completion time of the work itself — result() may be called
-        # arbitrarily late (an open-loop client draining handles in submit
-        # order), and stamping at assembly would bill that drain wait as
-        # service latency.
-        completed_at = time.time()
-        now_mono = time.monotonic()
-        for i, it in enumerate(items):
-            it.completed_at = completed_at
-            dt = now_mono - it.enqueued_at
-            self.scheduler.queue_hist.observe(dt)
-            self._m_queue_s.observe(dt)
-            res = {}
-            # ambient trace for the cache tiers' disk-write spans
-            with obs_trace.use_trace(it.trace_id):
-                for alg in algorithms:
-                    sliced = {k: v[i] for k, v in out[alg].items()}
-                    if caching:
-                        # freeze = an owned copy, so a cache entry never
-                        # pins the whole batch buffer it was sliced from
-                        sliced = self.cache.put(
-                            (it.digest, alg, it.cfg_digest), sliced)
-                    res[alg] = sliced
-            if tracing:
-                obs_trace.emit_span("exec", "batch", t_kernel, now_mono,
-                                    trace_id=it.trace_id,
-                                    parent_id=batch_span or "",
-                                    replica=self.name, bucket=bucket,
-                                    batch_size=len(items))
-            # first-wins settle: a concurrent kill() may have failed this
-            # item already (serve/scheduler.py::WorkItem.resolve)
-            it.resolve((res, it.batch_size, completed_at))
+        with obs_trace.span("device_step", "kernel", trace_id="",
+                            replica=self.name, bucket=bucket,
+                            batch_size=len(items),
+                            algorithms=",".join(algorithms)) as batch_span:
+            out = jax.device_get(fn(tiles, headers))   # one host transfer
+        self._m_step_s.observe(time.monotonic() - t_kernel)
+        with obs_trace.span("deliver", "batch", trace_id="",
+                            replica=self.name, bucket=bucket):
+            for res in out.values():
+                for v in res.values():
+                    v.setflags(write=False)        # responses are read-only
+            caching = self.cache.capacity > 0
+            for i, it in enumerate(items):
+                res = {}
+                # ambient trace for the cache tiers' disk-write spans
+                with obs_trace.use_trace(it.trace_id):
+                    for alg in algorithms:
+                        sliced = {k: v[i] for k, v in out[alg].items()}
+                        if caching:
+                            # freeze = an owned copy, so a cache entry
+                            # never pins the whole batch buffer it was
+                            # sliced from
+                            sliced = self.cache.put(
+                                (it.digest, alg, it.cfg_digest), sliced)
+                        res[alg] = sliced
+                # service-time stamp: this item's answer is ready NOW.  It
+                # rides in the future payload so ResponseHandle reports
+                # when the work was done — result() may be called
+                # arbitrarily late (an open-loop client draining handles in
+                # submit order), and stamping at assembly would bill that
+                # drain wait as service latency.
+                it.completed_at = time.time()
+                if tracing:
+                    obs_trace.emit_span("exec", "batch", t_kernel,
+                                        time.monotonic(),
+                                        trace_id=it.trace_id,
+                                        parent_id=batch_span or "",
+                                        replica=self.name, bucket=bucket,
+                                        batch_size=len(items))
+                # first-wins settle: a concurrent kill() may have failed
+                # this item already (serve/scheduler.py::WorkItem.resolve)
+                it.resolve((res, it.batch_size, it.completed_at))
         self.busy_s += time.monotonic() - t_start
         self.steps += 1
 

@@ -76,8 +76,9 @@ class WorkItem:
     cfg_digest: str
     future: Future
     enqueued_at: float = 0.0
+    taken_at: float = 0.0            # monotonic, when its batch formed
     batch_size: int = 0              # filled by the runner
-    completed_at: float = 0.0        # wall clock at batch completion (runner)
+    completed_at: float = 0.0        # wall clock once sliced and cached
     trace_id: str = ""               # minted at router admission (obs/trace)
     settled: bool = False            # first resolve/fail wins; rest no-op
     _settle_lock: threading.Lock = dataclasses.field(
@@ -140,11 +141,14 @@ class BatchScheduler:
         self.items = 0
         self.rejected = 0
         self.batch_size_hist: Dict[int, int] = {}
-        # queue latency (enqueue → batch completion, seconds) — observed
-        # by the service runner into a fixed-bucket histogram: bounded
-        # memory forever (the old per-request deque grew with traffic and
-        # its np.percentile sorted on every stats() poll), quantiles
-        # answered by interpolated bucket walk (obs/metrics.py)
+        # exact total of every taken item's queue wait (enqueue → batch
+        # formation, seconds): the window's mean wait is a difference of
+        # two ``stats()`` snapshots over the difference of ``items``
+        self.wait_s = 0.0
+        # the same waits in a fixed-bucket histogram: bounded memory
+        # forever (the old per-request deque grew with traffic and its
+        # np.percentile sorted on every stats() poll), quantiles answered
+        # by interpolated bucket walk (obs/metrics.py)
         self.queue_hist = obs_metrics.Histogram(f"{name}.queue_s")
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name=name)
@@ -196,29 +200,38 @@ class BatchScheduler:
     # ---- runner side -------------------------------------------------------
     def _take_batch(self) -> Tuple[tuple, List[WorkItem]]:
         """Form the next batch (called with the lock held, queue non-empty):
-        oldest item keys the group; wait for fill or the head's deadline."""
+        oldest item keys the group; wait for fill or the head's deadline,
+        in one ``fill`` span.  Stamps ``taken_at`` on the taken items."""
         head = self._pending[0]
         key = head.group_key
         deadline = head.enqueued_at + self.max_batch_delay_s
-        while not self._stopping:
-            group = [it for it in self._pending if it.group_key == key]
-            if len(group) >= self.max_batch:
-                break
-            rem = deadline - time.monotonic()
-            if rem <= 0:
-                break
-            self._cv.wait(rem)
+        with obs_trace.span("fill", "scheduler"):
+            while not self._stopping:
+                group = [it for it in self._pending if it.group_key == key]
+                if len(group) >= self.max_batch:
+                    break
+                rem = deadline - time.monotonic()
+                if rem <= 0:
+                    break
+                self._cv.wait(rem)
         group = [it for it in self._pending
                  if it.group_key == key][:self.max_batch]
         taken = {it.seq for it in group}
         self._pending = [it for it in self._pending if it.seq not in taken]
+        now = time.monotonic()
+        for it in group:
+            it.taken_at = now
+            self.wait_s += now - it.enqueued_at
+            self.queue_hist.observe(now - it.enqueued_at)
         return key, group
 
     def _loop(self):
         while True:
             with self._cv:
-                while not self._pending and not self._stopping:
-                    self._cv.wait()
+                if not self._pending and not self._stopping:
+                    with obs_trace.span("idle", "scheduler"):
+                        while not self._pending and not self._stopping:
+                            self._cv.wait()
                 if not self._pending and self._stopping:
                     return
                 (bucket, algorithms), batch = self._take_batch()
@@ -283,12 +296,14 @@ class BatchScheduler:
 
     def stats(self) -> Dict[str, object]:
         """Counter snapshot: totals, queue depth, batch-size histogram /
-        mean occupancy, and p50/p99 queue latency (enqueue → batch
-        completion) estimated from the bounded fixed-bucket histogram
+        mean occupancy, ``wait_s`` (the exact sum of taken items' queue
+        waits, enqueue → batch formation), and p50/p99 of that wait
+        estimated from the bounded fixed-bucket histogram
         (`obs/metrics.py::Histogram` — constant memory at any traffic
         volume, interpolated quantiles)."""
         with self._cv:
             snap = {"batches": self.batches, "items": self.items,
+                    "wait_s": self.wait_s,
                     "submitted": self._seq,
                     "rejected": self.rejected,
                     "queue_depth": len(self._pending),

@@ -254,3 +254,38 @@ def test_pad_to_multiple():
     r0 = jax.jit(lambda t, h: extract_features(t, h, "harris", b.cfg))(
         b.tiles, b.headers)
     assert int(r["total_count"]) == int(r0["total_count"])   # pads emit nothing
+
+
+
+RESPONSE_SCOPE = {"harris": "harris", "shi_tomasi": "shi_tomasi",
+                  "sift": "sift", "surf": "surf", "fast": "fast",
+                  "brief": "fast", "orb": "fast"}
+
+
+@pytest.mark.parametrize("alg", sorted(RESPONSE_SCOPE))
+def test_named_scopes_in_job_and_serve_programs(alg, tmp_path):
+    """The job's sharded program and the serve step carry the named
+    scopes a device trace is read by: the response function's
+    ``difet.response.<fn>``, and ``difet.<alg>`` over ``nms``, ``topk``,
+    ``describe`` (descriptor algorithms) and ``reduce``."""
+    import re
+
+    from repro.core.engine import ALGORITHMS, make_serve_step
+    from repro.core.job import DifetJob
+    from repro.distributed.sharding import data_mesh
+    cfg = DifetConfig(tile=32, halo=8, max_keypoints_per_tile=16)
+    tiles = np.zeros((2, 48, 48), np.float32)
+    headers = np.zeros((2, 6), np.int32)
+    job = DifetJob(BundleStore(tmp_path), alg, mesh=data_mesh(1),
+                   manifest_path=tmp_path / "job.manifest.json")
+    stages = ["nms", "topk", "reduce"]
+    if ALGORITHMS[alg].describe is not None:
+        stages.append("describe")
+    for fn in (job._sharded_fn(tiles.shape, cfg),
+               make_serve_step((alg,), cfg)):
+        text = fn.lower(tiles, headers).as_text(debug_info=True)
+        assert f"difet.response.{RESPONSE_SCOPE[alg]}" in text
+        for stage in stages:
+            assert re.search(rf"difet\.{alg}\)?/{stage}\b", text), stage
+        if "describe" not in stages:
+            assert not re.search(rf"difet\.{alg}\)?/describe", text)

@@ -180,6 +180,33 @@ def test_span_ids_ambient_trace_and_attrs(flight):
     assert child.parent_id == s.span_id and child.span_id == sid
 
 
+def test_span_writes_a_profiler_traceme_and_yields_its_id(flight, tmp_path):
+    """Every span() is also a ``difet.<layer>.<name>`` TraceMe in an active
+    ``jax.profiler`` session (on the device trace's clock); the recorded
+    span's id is what the block gets, so children can name their parent.
+    emit_span() is recorder-only: a TraceMe cannot be back-dated."""
+    import jax
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs_trace.span("deliver", "batch") as sid:
+            with obs_trace.span("disk_put", "cache"):
+                pass
+        obs_trace.emit_span("queue_wait", "scheduler", 0.0, 1.0)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    names = [e.name for plane in ProfileData.from_file(str(path)).planes
+             for line in plane.lines for e in line.events
+             if e.name.startswith("difet.")]
+    assert sorted(names) == ["difet.batch.deliver", "difet.cache.disk_put"]
+    recorded = {s.name: s for s in flight.spans()}
+    assert sid == recorded["deliver"].span_id
+    obs_trace.set_recorder(NoopRecorder())
+    with obs_trace.span("deliver", "batch") as off:
+        assert off is None                          # nothing recorded
+
+
 # ---- exporters + validator -------------------------------------------------
 
 def _mk_span(name, layer, t0, t1, tid="t1"):

@@ -331,6 +331,53 @@ def test_fully_cached_response_reports_zero_queue_latency():
         svc.close()
 
 
+def test_scheduler_wait_is_enqueue_to_batch_formation():
+    """``wait_s`` and the queue histogram observe enqueue → batch
+    formation: a lone item waits out the batch delay, and a slow device
+    step after formation adds nothing to either."""
+    delay, step = 0.05, 0.3
+
+    def slow(bucket, algs, items):
+        time.sleep(step)
+        for it in items:
+            it.resolve(None)
+
+    sched = BatchScheduler(slow, max_batch=4, max_batch_delay_s=delay)
+    try:
+        fut = sched.submit(np.zeros((32, 32)), np.zeros(6), 32, ("harris",))
+        fut.result(10)
+        s = sched.stats()
+        assert s["items"] == 1
+        assert delay <= s["wait_s"] < step
+        assert sched.queue_hist.count == 1
+        assert sched.queue_hist.quantile(0.5) < step
+    finally:
+        sched.stop(10)
+
+
+def test_completed_at_is_stamped_after_slicing_and_caching(monkeypatch):
+    """``timing["completed_at"]`` is the moment the tile's answer was
+    ready: after its results were sliced and cached, not at the end of
+    the device step."""
+    svc = make_service(max_batch=4, cache_entries=64)
+    puts = []
+    real_put = svc.cache.put
+
+    def put(key, value):
+        out = real_put(key, value)
+        puts.append(time.time())
+        return out
+    monkeypatch.setattr(svc.cache, "put", put)
+    try:
+        svc.warmup([("harris",)])
+        r = svc.extract(synthetic_scene(32, 32, 911), ("harris",),
+                        timeout=60)
+        assert len(puts) == 1
+        assert r.timing["completed_at"] >= puts[0]
+    finally:
+        svc.close()
+
+
 # ---- scheduler: backpressure + coalescing ----------------------------------
 
 def test_scheduler_backpressure():
