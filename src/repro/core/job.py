@@ -14,6 +14,12 @@ bundles, and the stitching workload's pairwise-registration phase
 (`core/mosaic.py::MatchPhase`) reuses the same machinery for its match
 manifest.
 
+Read-ahead: a subclass that defines ``load`` splits each item into a
+host-side load stage and ``process``.  ``run`` then loads item *n+1* on
+one background thread (`_ReadAhead`) while item *n* is processed and
+committed, so the store read leaves the critical path; items are still
+loaded, processed and committed in manifest order.
+
 Multi-worker protocol (docs/scaling.md): the manifest's item order is
 fixed at creation and never rewritten — restart-determinism means any
 worker count walks the *same* ordered list.  Workers coordinate through
@@ -31,8 +37,9 @@ import json
 import os
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 from pathlib import Path
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -162,14 +169,67 @@ class LeaseBoard:
         return h is not None and h[1] < self.ttl_s
 
 
+class _ReadAhead:
+    """Depth-1 read-ahead over a job's claimed items: while the caller
+    processes one item, one background thread claims the next and runs
+    ``load`` on it.  Iterating yields ``(name, loaded)`` in claim order,
+    each after the caller's wait for it, recorded as a ``wait_load`` span
+    (layer ``job``; flight recorder only, so the job's profiler spans stay
+    those of the sequential loop, and a profiler trace reads the wait as
+    the gap between ``bundle`` spans); a ``load`` that raises raises
+    there, at its item.  ``close``
+    stops and joins the thread and returns the name of an item it read
+    ahead that was never taken (None if none), which is dropped."""
+
+    def __init__(self, load: Callable[[str], object], claims: Iterator[str]):
+        self._load, self._claims = load, claims
+        self._pool = ThreadPoolExecutor(
+            1, thread_name_prefix="difet-job-reader")
+        self._ahead = self._pool.submit(self._next)
+
+    def _next(self) -> Optional[Tuple[str, object]]:
+        # runs on the reader thread only, so ``claims`` (and in pool mode
+        # the lease it takes) advances one item at a time, before the load
+        name = next(self._claims, None)
+        return None if name is None else (name, self._load(name))
+
+    def __iter__(self) -> Iterator[Tuple[str, object]]:
+        first = True
+        while True:
+            t0 = time.monotonic()
+            got = self._ahead.result()
+            obs_trace.emit_span("wait_load", "job", t0, time.monotonic())
+            if got is None:
+                return
+            self._ahead = self._pool.submit(self._next)
+            if not first:
+                obs_metrics.registry().counter(
+                    "difet.job.readahead_hits").inc()
+            first = False
+            yield got
+
+    def close(self) -> Optional[str]:
+        self._ahead.cancel()
+        self._pool.shutdown(wait=True)
+        ahead = self._ahead
+        if ahead.cancelled() or ahead.exception() is not None \
+                or ahead.result() is None:
+            return None
+        obs_metrics.registry().counter("difet.job.readahead_dropped").inc()
+        return ahead.result()[0]
+
+
 class ManifestJob:
     """Checkpointed work queue over named items.
 
     ``run()`` is restartable: it consults the manifest, processes only
     missing items via ``process(name)`` (subclass hook), and commits the
     manifest write-tmp-then-rename after each item — the MapReduce "task
-    commit" analogue.  ``simulate_failure_after`` kills the job after N
-    items (used by the fault-tolerance tests).
+    commit" analogue.  A subclass that also defines ``load(name)`` gets
+    read-ahead: ``run`` calls ``process(name, load(name))``, with the
+    next item's ``load`` running on a background thread meanwhile.
+    ``simulate_failure_after`` kills the job after N items (used by the
+    fault-tolerance tests).
 
     ``run(worker_id=...)`` joins an elastic worker pool: items are walked
     in manifest order but claimed through the job's `LeaseBoard`, so any
@@ -187,6 +247,9 @@ class ManifestJob:
         self.shards_per_bundle = shards_per_bundle
         self.lease_ttl_s = lease_ttl_s
         self._items = items
+        # the reader thread ORs done marks from disk while the main thread
+        # serializes the manifest
+        self._done_lock = threading.Lock()
         self.manifest = self._load_or_create()
 
     def _load_or_create(self) -> JobManifest:
@@ -204,7 +267,9 @@ class ManifestJob:
         # same manifest must not consume each other's tmp file mid-replace
         tmp = self.manifest_path.with_suffix(
             f".tmp.{os.getpid()}.{threading.get_ident()}")
-        tmp.write_text(manifest.to_json())
+        with self._done_lock:
+            text = manifest.to_json()
+        tmp.write_text(text)
         tmp.replace(self.manifest_path)      # atomic manifest update
         obs_metrics.registry().counter("difet.job.manifest_commits").inc()
 
@@ -213,11 +278,12 @@ class ManifestJob:
         concurrent writer; a failed read just keeps the local view)."""
         try:
             disk = JobManifest.from_json(self.manifest_path.read_text())
+        except (OSError, ValueError, TypeError):
+            return
+        with self._done_lock:
             for n, d in disk.done.items():
                 if d:
                     self.manifest.done[n] = True
-        except (OSError, ValueError, TypeError):
-            pass
 
     def _commit_merged(self) -> None:
         """Multi-worker commit: re-read the on-disk manifest and OR the
@@ -237,27 +303,21 @@ class ManifestJob:
                 ttl_s=self.lease_ttl_s)
         return self._leases
 
-    def process(self, name: str) -> None:
-        """Produce + commit the result for one item (subclass hook)."""
+    #: Optional load stage (subclass hook): ``load(name)`` does an item's
+    #: host-side reading, and ``process(name, loaded)`` gets its result.
+    #: Defined, it turns on read-ahead in ``run``; ``None``, items are
+    #: processed one after another with ``process(name)``.
+    load: Optional[Callable[[str], object]] = None
+
+    def process(self, name: str, *loaded) -> None:
+        """Produce + commit the result for one item (subclass hook);
+        ``loaded`` is what ``load(name)`` returned, where it is defined."""
         raise NotImplementedError
 
-    def run(self, simulate_failure_after: Optional[int] = None,
-            progress: Optional[Callable[[str], None]] = None,
-            worker_id: Optional[str] = None) -> Dict:
-        """Process remaining items in manifest order; returns `summary()`.
-        Each item runs in a ``bundle`` span (layer ``job``, `obs/trace.py`)
-        around ``process`` and its manifest commit (``commit``).
-
-        Args:
-            simulate_failure_after: raise after N items (fault-tolerance
-                tests — the restart path is the recovery protocol).
-            progress: optional per-item callback with the item name.
-            worker_id: join the elastic worker pool under this identity —
-                items are claimed via the lease board, skipped when
-                another live worker holds them, and released on commit.
-                ``None`` (single-worker mode) bypasses leasing entirely.
-        """
-        processed = 0
+    def _claims(self, worker_id: Optional[str]) -> Iterator[str]:
+        """The remaining items this run takes, in manifest order; in pool
+        mode only those this worker could lease, each leased before it is
+        yielded."""
         for name in list(self.manifest.remaining):
             if worker_id is not None:
                 if self.manifest.done.get(name):
@@ -270,22 +330,56 @@ class ManifestJob:
                     continue
                 if not self.leases.acquire(name, worker_id):
                     continue                    # leased by a live worker
-            with obs_trace.span("bundle", "job", item=name):
-                self.process(name)
-                self.manifest.done[name] = True
-                with obs_trace.span("commit", "job"):
-                    if worker_id is not None:
-                        self._commit_merged()
-                    else:
-                        self._commit(self.manifest)
-            if worker_id is not None:
-                self.leases.release(name, worker_id)
-            processed += 1
-            if progress:
-                progress(name)
-            if simulate_failure_after is not None \
-                    and processed >= simulate_failure_after:
-                raise RuntimeError(f"simulated worker failure after {name}")
+            yield name
+
+    def run(self, simulate_failure_after: Optional[int] = None,
+            progress: Optional[Callable[[str], None]] = None,
+            worker_id: Optional[str] = None) -> Dict:
+        """Process remaining items in manifest order; returns `summary()`.
+        Each item runs in a ``bundle`` span (layer ``job``, `obs/trace.py`)
+        around ``process`` and its manifest commit (``commit``).  With a
+        ``load`` stage the next item is claimed and loaded ahead on one
+        reader thread; however ``run`` leaves, that thread is joined
+        before it returns and an item it read ahead unprocessed is
+        dropped (its lease released).
+
+        Args:
+            simulate_failure_after: raise after N items (fault-tolerance
+                tests — the restart path is the recovery protocol).
+            progress: optional per-item callback with the item name.
+            worker_id: join the elastic worker pool under this identity —
+                items are claimed via the lease board, skipped when
+                another live worker holds them, and released on commit.
+                ``None`` (single-worker mode) bypasses leasing entirely.
+        """
+        claims = self._claims(worker_id)
+        reader = None if self.load is None else _ReadAhead(self.load, claims)
+        items = (((name, ()) for name in claims) if reader is None else
+                 ((name, (loaded,)) for name, loaded in reader))
+        processed = 0
+        try:
+            for name, loaded in items:
+                with obs_trace.span("bundle", "job", item=name):
+                    self.process(name, *loaded)
+                    self.manifest.done[name] = True
+                    with obs_trace.span("commit", "job"):
+                        if worker_id is not None:
+                            self._commit_merged()
+                        else:
+                            self._commit(self.manifest)
+                if worker_id is not None:
+                    self.leases.release(name, worker_id)
+                processed += 1
+                if progress:
+                    progress(name)
+                if simulate_failure_after is not None \
+                        and processed >= simulate_failure_after:
+                    raise RuntimeError(
+                        f"simulated worker failure after {name}")
+        finally:
+            dropped = reader.close() if reader is not None else None
+            if dropped is not None and worker_id is not None:
+                self.leases.release(dropped, worker_id)
         return self.summary()
 
     def summary(self) -> Dict:
@@ -345,11 +439,13 @@ class DifetJob(ManifestJob):
 
     def _shards(self, bundle: TileBundle) -> List[TileBundle]:
         """Over-decomposition for straggler mitigation: split tiles into
-        independent shards so slow/failed work is bounded per shard."""
+        independent shards so slow/failed work is bounded per shard.  The
+        split is contiguous, so each shard is a view of the bundle."""
         n = max(1, min(self.shards_per_bundle, len(bundle)))
-        splits = np.array_split(np.arange(len(bundle)), n)
-        return [TileBundle(bundle.tiles[s], bundle.headers[s], bundle.cfg)
-                for s in splits if len(s)]
+        return [TileBundle(t, h, bundle.cfg)
+                for t, h in zip(np.array_split(bundle.tiles, n),
+                                np.array_split(bundle.headers, n))
+                if len(t)]
 
     # ---- mesh-sharded extraction -------------------------------------------
     def _data_size(self) -> int:
@@ -422,19 +518,23 @@ class DifetJob(ManifestJob):
                 extract_features(tiles, headers, self.algorithm, cfg,
                                  use_pallas=self.use_pallas)}
 
-    def process(self, name: str) -> None:
-        """Extract one bundle: split into shards, extract each (device-
-        sharded when a mesh is set), merge shard partials, and commit one
-        ``<name>.<algorithm>`` result per algorithm to the store.  Spans
-        (layer ``job``): ``get`` (the store read), ``extract`` per shard
-        (on a mesh with its ``fetch``, the wait for the device and the
-        transfer), ``merge`` and ``put`` (the result writes)."""
+    def load(self, name: str) -> List[TileBundle]:
+        """Read one bundle and split it into shards: span ``get`` (layer
+        ``job``), on the job's reader thread (`ManifestJob.run`)."""
         with obs_trace.span("get", "job"):
-            bundle = self.store.get(name)
+            return self._shards(self.store.get(name))
+
+    def process(self, name: str, shards: List[TileBundle]) -> None:
+        """Extract one loaded bundle: extract each shard (device-sharded
+        when a mesh is set), merge shard partials, and commit one
+        ``<name>.<algorithm>`` result per algorithm to the store.  Spans
+        (layer ``job``): ``extract`` per shard (on a mesh with its
+        ``fetch``, the wait for the device and the transfer), ``merge``
+        and ``put`` (the result writes)."""
         partials: Dict[str, List[Dict]] = {}
-        for shard in self._shards(bundle):
+        for shard in shards:
             with obs_trace.span("extract", "job"):
-                r = self._extract(shard.tiles, shard.headers, bundle.cfg)
+                r = self._extract(shard.tiles, shard.headers, shard.cfg)
                 for alg, res in r.items():
                     partials.setdefault(alg, []).append(
                         {k: np.asarray(v) for k, v in res.items()})

@@ -11,6 +11,7 @@ from repro.configs.difet_paper import DifetConfig
 from repro.core.bundle import BundleStore, bundle_scenes
 from repro.core.job import DifetJob
 from repro.data.landsat import synthetic_scene
+from repro.obs import trace as obs_trace
 
 
 def make_store(tmp_path, n_bundles=3):
@@ -227,3 +228,178 @@ def test_mesh_padding_slice_matches_unpadded(tmp_path):
     for k in ref:
         np.testing.assert_array_equal(np.asarray(padded[k]),
                                       np.asarray(ref[k]), err_msg=k)
+
+
+# ---- read-ahead -------------------------------------------------------------
+
+def _counter(name):
+    from repro.obs import metrics as obs_metrics
+    return obs_metrics.registry().counter(f"difet.job.{name}").value
+
+
+def _readers():
+    import threading
+    return [t for t in threading.enumerate()
+            if t.name.startswith("difet-job-reader")]
+
+
+class _SequentialJob(DifetJob):
+    """The same job without a load stage: ``run`` reads each bundle in
+    ``process``, one item after another (the reference loop)."""
+    load = None
+
+    def process(self, name):
+        super().process(name, DifetJob.load(self, name))
+
+
+def _results(store, names, algs):
+    return {(n, a, k): v for n in names for a in algs
+            for k, v in store.get_result(f"{n}.{a}").items()}
+
+
+def _assert_same_results(got, want):
+    assert got.keys() == want.keys()
+    for key in want:
+        np.testing.assert_array_equal(got[key], want[key], err_msg=str(key))
+
+
+@pytest.mark.parametrize("meshed", [False, True])
+@pytest.mark.parametrize("shards", [1, 4])
+def test_readahead_matches_sequential(tmp_path, meshed, shards):
+    """The read-ahead loop commits the same results, bit for bit, and the
+    same manifest as the loop that reads each bundle in turn; every item
+    after the first is handed over from the read-ahead."""
+    from repro.distributed.sharding import data_mesh
+    algs = ("harris", "fast")
+    kw = dict(shards_per_bundle=shards,
+              mesh=data_mesh(1) if meshed else None)
+    seq_store = make_store(tmp_path / "seq")
+    _SequentialJob(seq_store, ",".join(algs), **kw).run()
+    store = make_store(tmp_path / "ahead")
+    hits, dropped = _counter("readahead_hits"), _counter("readahead_dropped")
+    job = DifetJob(store, ",".join(algs), **kw)
+    prev = obs_trace.set_recorder(obs_trace.FlightRecorder())
+    try:
+        summary = job.run()
+        spans = obs_trace.get_recorder().spans()
+    finally:
+        obs_trace.set_recorder(prev)
+    assert summary["bundles_done"] == 3
+    # one wait per bundle, and the last for the end of the items
+    assert sum(sp.name == "wait_load" for sp in spans) == 3 + 1
+    assert _counter("readahead_hits") - hits == 3 - 1
+    assert _counter("readahead_dropped") == dropped
+    assert not _readers()
+    names = [f"b{i}" for i in range(3)]
+    _assert_same_results(_results(store, names, algs),
+                         _results(seq_store, names, algs))
+    got = json.loads(job.manifest_path.read_text())
+    want = json.loads((seq_store.root / "harris,fast.manifest.json")
+                      .read_text())
+    for m in (got, want):
+        m.pop("started_at")
+    assert got == want
+
+
+class _Stop(Exception):
+    pass
+
+
+class _FailingStore(BundleStore):
+    """A store whose ``get`` of one bundle raises, once."""
+
+    def __init__(self, root, fail_on):
+        super().__init__(root)
+        self.fail_on = fail_on
+
+    def get(self, name):
+        if name == self.fail_on:
+            self.fail_on = None
+            raise OSError(f"unreadable bundle {name}")
+        return super().get(name)
+
+
+@pytest.mark.parametrize("exit_by,done,n_dropped", [
+    ("progress", ["b0"], 1),
+    ("simulated_failure", ["b0"], 1),
+    ("load_error", ["b0", "b1"], 0),
+])
+def test_readahead_exit_joins_reader_and_resumes(tmp_path, exit_by, done,
+                                                 n_dropped):
+    """However ``run`` leaves, the items before the exit are committed,
+    the reader thread is joined, a bundle it read ahead is dropped, and a
+    restart completes the job bit-identically to an uninterrupted one."""
+    names = [f"b{i}" for i in range(4)]
+    ref_store = make_store(tmp_path / "ref", n_bundles=4)
+    _SequentialJob(ref_store, "harris").run()
+    make_store(tmp_path, n_bundles=4)
+    store = _FailingStore(tmp_path / "store",
+                          "b2" if exit_by == "load_error" else None)
+
+    def stop(name):
+        raise _Stop(name)
+
+    dropped = _counter("readahead_dropped")
+    job = DifetJob(store, "harris")
+    if exit_by == "progress":
+        with pytest.raises(_Stop):
+            job.run(progress=stop)
+    elif exit_by == "simulated_failure":
+        with pytest.raises(RuntimeError, match="simulated worker failure"):
+            job.run(simulate_failure_after=1)
+    else:
+        with pytest.raises(OSError, match="unreadable bundle b2"):
+            job.run()
+    m = json.loads(job.manifest_path.read_text())
+    assert [n for n in names if m["done"][n]] == done
+    assert all(store.has_result(f"{n}.harris") == (n in done)
+               for n in names)
+    assert not _readers()
+    assert _counter("readahead_dropped") - dropped == n_dropped
+    assert DifetJob(store, "harris").run()["bundles_done"] == 4
+    assert not _readers()
+    _assert_same_results(_results(store, names, ("harris",)),
+                         _results(ref_store, names, ("harris",)))
+
+
+def test_readahead_reads_only_leased_items(tmp_path):
+    """Two concurrent pool workers with the read-ahead: each bundle is read
+    only by a worker that holds its lease at that moment, and the merged
+    results match the uninterrupted reference."""
+    import threading
+    from repro.core.job import LeaseBoard
+    names = [f"b{i}" for i in range(6)]
+    ref_store = make_store(tmp_path / "ref", n_bundles=6)
+    _SequentialJob(ref_store, "fast").run()
+    make_store(tmp_path, n_bundles=6)
+    reads = []
+
+    class WorkerStore(BundleStore):
+        def __init__(self, root, worker):
+            super().__init__(root)
+            self.worker = worker
+
+        def get(self, name):
+            board = LeaseBoard(self.root / "fast.manifest.leases")
+            reads.append((name, self.worker, board.holder(name)))
+            return super().get(name)
+
+    def worker(wid):
+        DifetJob(WorkerStore(tmp_path / "store", wid), "fast").run(
+            worker_id=wid)
+
+    threads = [threading.Thread(target=worker, args=(f"w{i}",))
+               for i in range(2)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=300)
+        assert not t.is_alive()
+    assert not _readers()
+    assert {n for n, _, _ in reads} == set(names)
+    for name, wid, holder in reads:
+        assert holder is not None and holder[0] == wid, (name, wid, holder)
+    store = BundleStore(tmp_path / "store")
+    assert all(store.has_result(f"{n}.fast") for n in names)
+    _assert_same_results(_results(store, names, ("fast",)),
+                         _results(ref_store, names, ("fast",)))
