@@ -6,8 +6,10 @@ Open-loop injection at the trace's arrival offsets through the router:
 admission control sheds (typed: tenant quota vs fleet saturation), the
 consistent-hash ring routes hot scenes to their affinity replicas, and
 the shared disk cache tier turns cross-replica repeats into hits.
-``--proc`` spawns replicas as OS processes (`serve/proc.py`) over the
-spooled-file transport.  ``--autoscale`` runs the SLO-driven autoscaler
+Thread replicas take the process's devices one each, round-robin
+(`serve/fleet.py`): ``--replicas 4`` on a four-chip host serves from
+four chips.  ``--proc`` spawns replicas as OS processes (`serve/proc.py`)
+over the spooled-file transport.  ``--autoscale`` runs the SLO-driven autoscaler
 during the replay; ``--kill-after N`` kills a replica after N accepted
 requests — for process replicas that is a raw ``kill -9`` detected only
 via the stale lease (chaos: the run must still complete every accepted
@@ -159,7 +161,8 @@ def report(label, wall, responses, sheds, fleet):
           f"misses={s['total_cache_misses']}  "
           f"busy={s['total_busy_s']:.2f}s")
     for name, r in sorted(s["replicas"].items()):
-        print(f"  {name}: submitted={r['submitted']} "
+        print(f"  {name}: device={r.get('device', '-')} "
+              f"submitted={r['submitted']} "
               f"batches={r['batches']} occ={r['batch_occupancy']:.2f} "
               f"p99q={r['p99_queue_ms']:.1f}ms state="
               f"{s['states'].get(name, '?')}")

@@ -37,6 +37,7 @@ import io
 import json
 import threading
 import time
+import weakref
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
@@ -163,6 +164,43 @@ class ResponseHandle:
         request has a result (``result()`` will not block)."""
         return all(p.future is None or p.future.done() for p in self._parts)
 
+    def add_done_callback(self, fn) -> None:
+        """Call ``fn(self)`` once, when every tile of the request is
+        settled (answered or failed): in the thread that settles the
+        last one, or here and now if none is pending.  The tiles'
+        futures hold the handle only weakly, so a handle that nobody
+        else holds is freed by reference counting and never waits for
+        the cycle collector, whose full collections stop every thread."""
+        futures = [p.future for p in self._parts if p.future is not None]
+        if not futures:
+            fn(self)
+            return
+        me, left, lock = weakref.ref(self), [len(futures)], threading.Lock()
+
+        def one(_future):
+            with lock:
+                left[0] -= 1
+                last = left[0] == 0
+            handle = me()
+            if last and handle is not None:
+                fn(handle)
+        for f in futures:
+            f.add_done_callback(one)
+
+    @property
+    def completed_at(self) -> Optional[float]:
+        """When the request's work finished (wall clock, the latest of
+        its tiles' stamps; submit time when fully cached), or None while
+        a tile is pending or if one failed."""
+        at = self._enqueued_at
+        for p in self._parts:
+            if p.future is None:
+                continue
+            if not p.future.done() or p.future.exception() is not None:
+                return None
+            at = max(at, p.future.result()[2])
+        return at
+
     def result(self, timeout: Optional[float] = None) -> ExtractResponse:
         """Assemble the response; ``timeout`` is a total deadline across
         every tile of the request, not per tile.
@@ -178,15 +216,13 @@ class ResponseHandle:
         deadline = None if timeout is None else time.monotonic() + timeout
         per_tile: List[Dict[str, Dict[str, np.ndarray]]] = []
         batch_sizes: List[int] = []
-        completed_at = self._enqueued_at       # fully-cached: no device wait
         for p in self._parts:
             if p.future is None:
                 per_tile.append(dict(p.cached))
                 continue
             rem = None if deadline is None else deadline - time.monotonic()
-            computed, batch_size, part_done = p.future.result(rem)
+            computed, batch_size, _ = p.future.result(rem)
             batch_sizes.append(batch_size)
-            completed_at = max(completed_at, part_done)
             if not p.cached:
                 per_tile.append(computed)
                 continue
@@ -201,6 +237,7 @@ class ResponseHandle:
                        for alg in self.algorithms}
         cached = {alg: sum(1.0 for p in self._parts if alg not in p.missing)
                   / len(self._parts) for alg in self.algorithms}
+        completed_at = self.completed_at
         return ExtractResponse(
             request_id=self.request_id, algorithms=self.algorithms,
             results=results, n_tiles=len(self._parts), bucket=self._bucket,
@@ -215,16 +252,25 @@ class ResponseHandle:
 
 class FeatureService:
     """In-process DIFET feature-extraction service (the unit a fleet of
-    workers would replicate behind a load balancer)."""
+    workers would replicate behind a load balancer).
+
+    ``device`` is the device the service runs on: its warm-up, its
+    compiled steps and every batch canvas are committed to it
+    (`serve/buckets.py::CompileCache.place`), so replicas in one process
+    can each hold a chip of their own.  None runs on the default device,
+    with the batch handed to jit as host arrays."""
 
     def __init__(self, cfg: Optional[ServeConfig] = None, *,
                  name: str = "difet-serve",
-                 step_lock: Optional[threading.Lock] = None):
+                 step_lock: Optional[threading.Lock] = None,
+                 device=None):
         self.cfg = cfg or ServeConfig()
         self.name = name
+        self.device_id = (device if device is not None
+                          else jax.devices()[0]).id
         self.table = BucketTable(self.cfg.buckets, self.cfg.base)
         self.compile_cache = CompileCache(self.table, self.cfg.max_batch,
-                                          self.cfg.use_pallas)
+                                          self.cfg.use_pallas, device)
         if self.cfg.cache_dir:
             self.cache = TieredResultCache(self.cfg.cache_entries,
                                            self.cfg.cache_dir)
@@ -429,10 +475,11 @@ class FeatureService:
             fn = self.compile_cache.get(bucket, algorithms)
         t_kernel = time.monotonic()
         with obs_trace.span("device_step", "kernel", trace_id="",
-                            replica=self.name, bucket=bucket,
-                            batch_size=len(items),
+                            replica=self.name, device=self.device_id,
+                            bucket=bucket, batch_size=len(items),
                             algorithms=",".join(algorithms)) as batch_span:
-            out = jax.device_get(fn(tiles, headers))   # one host transfer
+            out = jax.device_get(      # one host transfer
+                fn(*self.compile_cache.place(tiles, headers)))
         self._m_step_s.observe(time.monotonic() - t_kernel)
         with obs_trace.span("deliver", "batch", trace_id="",
                             replica=self.name, bucket=bucket):
@@ -485,8 +532,9 @@ class FeatureService:
     def stats(self) -> Dict[str, object]:
         """Operational counters, cheap enough for an autoscaler to poll:
         nested result-cache / scheduler detail plus a flat per-replica
-        snapshot (``submitted``/``shed`` requests, cache hit/miss, batch
-        occupancy, p50/p99 queue latency, device busy seconds) that
+        snapshot (``device`` id, ``submitted``/``shed`` requests, cache
+        hit/miss, batch occupancy, p50/p99 queue latency, device busy
+        seconds) that
         `serve/router.py::Router.stats` aggregates across the fleet."""
         sched = self.scheduler.stats()
         cache = self.cache.stats()
@@ -496,6 +544,7 @@ class FeatureService:
                 "program_keys": self.compile_cache.keys(),
                 # flat per-replica counters (the fleet aggregation surface)
                 "name": self.name,
+                "device": self.device_id,
                 "submitted": self.requests,
                 "shed": self.shed,
                 "cache_hits": cache["hits"],

@@ -7,6 +7,13 @@ replicate".  Replicas come in two kinds:
 * **thread** (default): an in-process `serve/api.py::FeatureService`
   (its own continuous-batching scheduler, compile cache, local result
   LRU) — cheap, shares the heap, the unit-test and benchmark workhorse.
+  Each thread replica runs on a device of its own while there are
+  devices to spare: a new replica takes the first of
+  ``jax.local_devices()`` that holds the fewest live replicas, so
+  replica *i* of a fresh fleet gets device *i* round-robin (four
+  replicas on a four-chip host: one per chip; on a one-device host they
+  all share device 0).  The layout follows from the devices the process
+  sees; no option picks it.
 * **process** (``FleetConfig.proc=True``): a `serve/proc.py` worker
   spawned as an OS process, driven through the spooled-file transport
   (`serve/transport.py`).  Nothing is shared but what a distributed
@@ -54,6 +61,8 @@ import threading
 import time
 from pathlib import Path
 from typing import Dict, List, Optional, Sequence, Tuple
+
+import jax
 
 from repro.core.job import LeaseBoard
 from repro.obs import metrics as obs_metrics
@@ -128,10 +137,12 @@ class Replica:
     """One pool member: the service (or process-replica client) plus its
     lifecycle state and kind (``"thread"`` | ``"proc"``)."""
 
-    def __init__(self, name: str, service, kind: str = "thread"):
+    def __init__(self, name: str, service, kind: str = "thread",
+                 device=None):
         self.name = name
         self.service = service
         self.kind = kind
+        self.device = device          # thread replicas: the device it holds
         self.state = SPAWNING
 
     def runner_alive(self) -> bool:
@@ -252,9 +263,10 @@ class Fleet:
         with self._lock:
             self._counter += 1
             name = f"replica-{self._counter}"
+            device = self._next_device()
             svc = FeatureService(self._serve_cfg(), name=name,
-                                 step_lock=self._step_lock)
-            rep = Replica(name, svc)
+                                 step_lock=self._step_lock, device=device)
+            rep = Replica(name, svc, device=device)
             self.replicas[name] = rep
         rep.state = WARMING
         svc.warmup(self.cfg.warm_algorithm_sets)
@@ -265,6 +277,18 @@ class Fleet:
         self.router.add_replica(name, svc)
         self._g_ready.set(len(self.ready_replicas()))
         return name
+
+    def _next_device(self):
+        """The local device that holds the fewest live thread replicas,
+        the first in ``jax.local_devices()`` order on a tie (call with
+        ``_lock`` held)."""
+        devices = jax.local_devices()
+        held = [0] * len(devices)
+        index = {d.id: i for i, d in enumerate(devices)}
+        for r in self.replicas.values():
+            if r.device is not None and r.state not in (RETIRED, DEAD):
+                held[index[r.device.id]] += 1
+        return devices[held.index(min(held))]
 
     def drain_replica(self, name: str, timeout: float = 60.0) -> None:
         """READY → DRAINING → RETIRED: leave the ring, finish every queued

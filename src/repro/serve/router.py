@@ -18,8 +18,16 @@ name → replica map).  A request flows:
         the least-pending replica instead (affinity is a cache
         optimization, never a correctness constraint — extraction is
         deterministic, so any replica computes the same bits)
-      → the request is registered in the outstanding table, submitted to
-        the replica, and a :class:`FleetHandle` returned.
+      → the request is submitted to the replica, registered in the
+        outstanding table, and a :class:`FleetHandle` returned.  A
+        done-callback on the replica's handle drops it from the table
+        once the replica has answered it, whether or not the caller ever
+        collects the answer (open-loop clients collect few); process
+        replicas' handles have no callbacks, and leave it on collection.
+
+The router's own work per request (admission, route key, pick and
+bookkeeping; not the replica's ``submit``) runs in ``difet.router.route``
+spans, and its seconds add up exactly in ``stats()["route_s"]``.
 
 Crash handling: when a replica dies (`Fleet.kill_replica`, or a stale
 liveness lease), every outstanding request routed to it is *re-admitted*
@@ -35,9 +43,11 @@ from __future__ import annotations
 
 import bisect
 import dataclasses
+import functools
 import hashlib
 import threading
 import time
+import weakref
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -213,6 +223,13 @@ class FleetHandle:
         """The fleet-assigned request id (stable across re-admissions)."""
         return self._req.rid
 
+    @property
+    def replica(self) -> str:
+        """The replica the request is routed to now (re-admission moves
+        it)."""
+        with self._router._cv:
+            return self._req.replica
+
     def done(self) -> bool:
         """Non-blocking readiness probe (False while a re-admitted
         request is still recomputing)."""
@@ -271,6 +288,7 @@ class Router:
         self._rid = 0
         # counters
         self.submitted = 0
+        self.route_s = 0.0          # the router's own seconds in submit()
         self.readmitted = 0
         self.routed_affinity = 0
         self.routed_spill = 0
@@ -404,35 +422,19 @@ class Router:
         ``max_global_pending``, or no replica is accepting work.  Never
         blocks the caller on backpressure — shedding at the edge is the
         contract."""
-        if self._closed:
-            self._shed(SHED_CLOSED, tenant, "router is closed")
-        ok, retry = self._bucket(tenant).take()
-        if not ok:
-            self._shed(SHED_TENANT_THROTTLED, tenant,
-                       f"tenant {tenant!r} over rate limit",
-                       retry_after_s=retry)
-        if self.total_pending() >= self.cfg.max_global_pending:
-            self._shed(SHED_FLEET_SATURATED, tenant,
-                       f"fleet queue at max_global_pending="
-                       f"{self.cfg.max_global_pending}")
-        key = self._route_key(image, scene_key)
-        with self._cv:
-            name, spilled = self._pick(key)
-            if name is None:
-                # release the lock before raising (shed takes it again)
-                pass
-            else:
-                slot = self._slots[name]
-        if name is None:
-            self._shed(SHED_NO_REPLICA, tenant, "no replica accepting work")
-        # trace id minted at admission (the request passed every gate):
-        # it follows the request through the replica scheduler, batch
-        # execution, the cache tiers, and crash re-admission
-        tracing = obs_trace.enabled()
-        tid = obs_trace.new_trace_id() if tracing else ""
-        t_admit = time.monotonic() if tracing else 0.0
-        admitted_at = time.time()        # SLO latency base (wall clock,
-        #                                  comparable to timing["completed_at"])
+        t_route = time.perf_counter()
+        with obs_trace.span("route", "router"):
+            name, slot, key, spilled = self._admit(image, tenant, scene_key)
+            # trace id minted at admission (the request passed every
+            # gate): it follows the request through the replica
+            # scheduler, batch execution, the cache tiers, and crash
+            # re-admission
+            tracing = obs_trace.enabled()
+            tid = obs_trace.new_trace_id() if tracing else ""
+            t_admit = time.monotonic() if tracing else 0.0
+            admitted_at = time.time()    # SLO latency base (wall clock,
+            #                              comparable to timing["completed_at"])
+        t_replica = time.perf_counter()
         try:
             handle = slot.service.submit(image, algorithms,
                                          request_id=request_id, block=False,
@@ -453,29 +455,60 @@ class Router:
             except (ServiceOverloaded, ServiceClosed):
                 self._shed(SHED_FLEET_SATURATED, tenant,
                            "all replicas overloaded")
+        t_book = time.perf_counter()
+        with obs_trace.span("route", "router"):
+            with self._cv:
+                self._rid += 1
+                rid = request_id or f"fleet-{self._rid:08d}"
+                req = _FleetRequest(rid, image, tuple(algorithms) if
+                                    not isinstance(algorithms, str)
+                                    else algorithms, tenant, key, name,
+                                    handle, trace_id=tid,
+                                    admitted_at=admitted_at)
+                self._outstanding[rid] = req
+                self.submitted += 1
+                if spilled:
+                    self.routed_spill += 1
+                else:
+                    self.routed_affinity += 1
+                t = self.tenant_counts.setdefault(
+                    tenant, {"admitted": 0, "shed": 0})
+                t["admitted"] += 1
+            self._m_admitted.inc()
+            (self._m_spill if spilled else self._m_affinity).inc()
+            if tracing:
+                obs_trace.emit_span("admit", "router", t_admit,
+                                    time.monotonic(), trace_id=tid, rid=rid,
+                                    tenant=tenant, replica=name,
+                                    spilled=spilled)
+            self._watch(req, handle)
+        own = (t_replica - t_route) + (time.perf_counter() - t_book)
         with self._cv:
-            self._rid += 1
-            rid = request_id or f"fleet-{self._rid:08d}"
-            req = _FleetRequest(rid, image, tuple(algorithms) if
-                                not isinstance(algorithms, str)
-                                else algorithms, tenant, key, name, handle,
-                                trace_id=tid, admitted_at=admitted_at)
-            self._outstanding[rid] = req
-            self.submitted += 1
-            if spilled:
-                self.routed_spill += 1
-            else:
-                self.routed_affinity += 1
-            t = self.tenant_counts.setdefault(
-                tenant, {"admitted": 0, "shed": 0})
-            t["admitted"] += 1
-        self._m_admitted.inc()
-        (self._m_spill if spilled else self._m_affinity).inc()
-        if tracing:
-            obs_trace.emit_span("admit", "router", t_admit, time.monotonic(),
-                                trace_id=tid, rid=rid, tenant=tenant,
-                                replica=name, spilled=spilled)
+            self.route_s += own
         return FleetHandle(self, req)
+
+    def _admit(self, image, tenant: str, scene_key: Optional[str]):
+        """Admission control, route key and pick: ``(replica name, its
+        slot, route key, spilled?)``, or a raised :class:`Shed`."""
+        if self._closed:
+            self._shed(SHED_CLOSED, tenant, "router is closed")
+        ok, retry = self._bucket(tenant).take()
+        if not ok:
+            self._shed(SHED_TENANT_THROTTLED, tenant,
+                       f"tenant {tenant!r} over rate limit",
+                       retry_after_s=retry)
+        if self.total_pending() >= self.cfg.max_global_pending:
+            self._shed(SHED_FLEET_SATURATED, tenant,
+                       f"fleet queue at max_global_pending="
+                       f"{self.cfg.max_global_pending}")
+        key = self._route_key(image, scene_key)
+        with self._cv:
+            name, spilled = self._pick(key)
+            slot = None if name is None else self._slots[name]
+        if name is None:
+            # shed outside the lock (it takes the lock again)
+            self._shed(SHED_NO_REPLICA, tenant, "no replica accepting work")
+        return name, slot, key, spilled
 
     def extract(self, image, algorithms, tenant: str = "default",
                 scene_key: Optional[str] = None,
@@ -530,6 +563,7 @@ class Router:
                     req.error = e
                     self._cv.notify_all()
                 continue
+            self._watch(req, new_handle)
             with self._cv:
                 req.replica = target
                 req.handle = new_handle
@@ -576,12 +610,38 @@ class Router:
         """Record one admission→work-completion latency into the fleet
         SLO histogram (idempotent per request — ``result()`` can be
         called repeatedly and `harvest_latencies` races it benignly)."""
+        self._observe_at(req, resp.timing.get("completed_at") or time.time())
+
+    def _observe_at(self, req: _FleetRequest, completed: float) -> None:
         with self._cv:
             if req.latency_observed or not req.admitted_at:
                 return
             req.latency_observed = True
-        completed = resp.timing.get("completed_at") or time.time()
         self._m_latency.observe(max(0.0, completed - req.admitted_at))
+
+    def _watch(self, req: _FleetRequest, handle) -> None:
+        """Have a thread replica's handle call `_settled` once settled
+        (process replicas' handles take no callbacks)."""
+        watch = getattr(handle, "add_done_callback", None)
+        if watch is not None:
+            watch(functools.partial(self._settled, weakref.ref(req)))
+
+    def _settled(self, ref, handle) -> None:
+        """Done-callback of a thread replica's handle (runs in the thread
+        that settled its last tile): an answered request's latency is
+        observed and it leaves the outstanding table.  A handle that
+        failed (its replica died) leaves the request there for
+        `readmit`.  The callback holds the request weakly (``ref``): once
+        it has left the table and its caller has dropped its handle,
+        reference counting frees the request, its tile and its
+        replica's handle."""
+        req, completed = ref(), handle.completed_at
+        if req is None or completed is None:
+            return
+        self._observe_at(req, completed)
+        with self._cv:
+            if self._outstanding.get(req.rid) is req:
+                del self._outstanding[req.rid]
 
     def harvest_latencies(self) -> int:
         """Observe the latency of every *done but uncollected* request —
@@ -610,19 +670,23 @@ class Router:
 
     @property
     def outstanding(self) -> int:
-        """Accepted requests not yet collected by their callers."""
+        """Accepted requests not yet answered (thread replicas) or not
+        yet collected by their callers (process replicas)."""
         with self._cv:
             return len(self._outstanding)
 
     # ---- ops ----------------------------------------------------------------
     def stats(self) -> Dict[str, object]:
         """Fleet-aggregated counters: router admission/routing totals,
-        per-tenant admit/shed, and the per-replica ``FeatureService``
+        ``route_s`` (the router's own seconds in ``submit``, the
+        replicas' ``submit`` left out), per-tenant admit/shed, and the
+        per-replica ``FeatureService``
         snapshots (plus their summed cache/queue totals)."""
         with self._cv:
             slots = dict(self._slots)
             snap = {
                 "submitted": self.submitted,
+                "route_s": self.route_s,
                 "shed": dict(self.shed_by_reason),
                 "shed_total": sum(self.shed_by_reason.values()),
                 "routed_affinity": self.routed_affinity,

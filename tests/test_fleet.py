@@ -394,3 +394,179 @@ def test_fleet_parity_over_trace(tmp_path):
         assert s["submitted"] == len(trace) and s["outstanding"] == 0
     finally:
         fleet.close()
+
+
+# ---- placement: one device per thread replica --------------------------------
+
+FOUR_DEVICES = r"""
+import dataclasses, json, sys
+sys.path[:0] = [{root!r}, {root!r} + "/src"]
+import jax
+import numpy as np
+from bench import compare
+from bench.refs import difet_features as ref
+from bench.scene import tile_scene
+from repro.configs.difet_paper import DifetConfig
+from repro.data.landsat import synthetic_scene
+from repro.serve import Fleet, FleetConfig, ServeConfig
+
+assert len(jax.local_devices()) == 4
+base = DifetConfig(tile=32, halo=8, max_keypoints_per_tile=16)
+algs = ("harris", "sift")
+fleet = Fleet(FleetConfig(
+    serve=ServeConfig(base=base, buckets=(32,), max_batch=4,
+                      cache_entries=0),
+    initial_replicas=4, min_replicas=4, max_replicas=4,
+    warm_algorithm_sets=(algs,)))
+seen = {{}}                    # replica -> devices of its steps' outputs
+for name, rep in fleet.replicas.items():
+    cc = rep.service.compile_cache
+    def get(bucket, a, real=cc.get, name=name):
+        fn = real(bucket, a)
+        def step(*args):
+            out = fn(*args)
+            seen.setdefault(name, set()).update(
+                d.id for leaf in jax.tree_util.tree_leaves(out)
+                for d in leaf.devices())
+            return out
+        return step
+    cc.get = get
+tiles = [synthetic_scene(32, 32, 1500 + i) for i in range(24)]
+handles = [fleet.submit(t, algs, scene_key=f"scene-{{i}}")
+           for i, t in enumerate(tiles)]
+got = [h.result(120) for h in handles]
+served = {{h.replica for h in handles}}
+stats = fleet.stats()["replicas"]
+fleet.close()
+p = dataclasses.asdict(base)
+batch = [tile_scene(np.asarray(t, np.float32), 32, 8) for t in tiles]
+want = ref.features(np.concatenate([b[0] for b in batch]),
+                    np.concatenate([b[1] for b in batch]), p, algs)
+def same(g, w):
+    # the count, which slots hold a keypoint, and every number of each
+    # keypoint, bit for bit (an empty slot's coordinates are unspecified)
+    v = np.asarray(w["top_valid"])
+    return (int(g["total_count"]) == int(w["total_count"])
+            and np.array_equal(g["top_valid"], v)
+            and all(np.array_equal(np.asarray(g[k])[v], np.asarray(w[k])[v])
+                    for k in w if k.startswith("top_")))
+exact = all(same(r.results[a], ref.request_result(want[a], j))
+            for j, r in enumerate(got) for a in algs)
+print("RESULT", json.dumps({{
+    "steps_on": {{n: sorted(d) for n, d in seen.items()}},
+    "replica_device": {{n: fleet.replicas[n].device.id for n in seen}},
+    "stats_device": {{n: s["device"] for n, s in stats.items()}},
+    "served": sorted(served), "exact": exact}}))
+"""
+
+
+def test_four_thread_replicas_run_on_four_devices(tmp_path):
+    """On four (virtual) devices each replica's steps run on a device of
+    its own, read from the step's output arrays before they are copied
+    to the host, and every answer equals the plain reference's, bit for
+    bit."""
+    import json
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+    root = Path(__file__).resolve().parents[1]
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=4"}
+    out = subprocess.run(
+        [sys.executable, "-c", FOUR_DEVICES.format(root=str(root))],
+        env=env, capture_output=True, text=True, timeout=600, cwd=tmp_path)
+    line = [x for x in out.stdout.splitlines() if x.startswith("RESULT ")]
+    assert line, out.stderr[-3000:]
+    got = json.loads(line[0][len("RESULT "):])
+    assert len(got["served"]) == 4 and len(got["steps_on"]) == 4
+    for name, devices in got["steps_on"].items():
+        assert devices == [got["replica_device"][name]], got
+        assert got["stats_device"][name] == devices[0]
+    assert sorted(got["replica_device"].values()) == [0, 1, 2, 3]
+    assert got["exact"]
+
+
+def test_thread_replicas_share_the_one_device():
+    """On a host with one device every replica runs on it, as before
+    replicas had devices of their own."""
+    fleet = Fleet(fleet_cfg(3, max_replicas=3))
+    try:
+        (only,) = jax.local_devices()
+        assert [r.device for r in fleet.replicas.values()] == [only] * 3
+        tiles = [synthetic_scene(32, 32, 1600 + i) for i in range(6)]
+        handles = [fleet.submit(t, ("harris",), scene_key=f"s-{i}")
+                   for i, t in enumerate(tiles)]
+        for t, h in zip(tiles, handles):
+            assert_results_equal(h.result(60).results, direct(t))
+        stats = fleet.stats()["replicas"]
+        assert {s["device"] for s in stats.values()} == {only.id}
+        for rep in fleet.replicas.values():
+            tiles_, headers = rep.service.compile_cache.empty_batch(32)
+            placed = rep.service.compile_cache.place(tiles_, headers)
+            assert {d for a in placed for d in a.devices()} == {only}
+    finally:
+        fleet.close()
+
+
+# ---- the router under an open loop ------------------------------------------
+
+def test_uncollected_answers_leave_the_outstanding_table():
+    """Open-loop clients never collect most answers: a request leaves
+    the router's table once its replica has answered it, and its
+    latency is observed all the same."""
+    fleet = Fleet(fleet_cfg(2, cache_entries=64))
+    try:
+        hist = fleet.router._m_latency
+        before = sum(hist.counts())
+        tiles = [synthetic_scene(32, 32, 1700 + i) for i in range(12)]
+        handles = [fleet.submit(t, ("harris",)) for t in tiles]
+        deadline = time.time() + 60
+        while fleet.router.outstanding and time.time() < deadline:
+            time.sleep(0.01)
+        assert fleet.router.outstanding == 0
+        assert sum(hist.counts()) - before == len(tiles)
+        assert all(h.done() for h in handles)
+        # a fully cached repeat settles at submit and never enters it
+        fleet.submit(tiles[0], ("harris",))
+        assert fleet.router.outstanding == 0
+        assert fleet.router.stats()["route_s"] > 0
+    finally:
+        fleet.close()
+
+
+def test_kill_readmits_every_unanswered_uncollected_request():
+    """A replica killed with requests queued: every request it had not
+    answered is re-admitted and answered, though no caller collected it
+    before the kill, and answered requests are not recomputed."""
+    step_lock = threading.Lock()
+    fleet = Fleet(fleet_cfg(2, max_batch=4), step_lock=step_lock)
+    try:
+        done_first = [fleet.submit(synthetic_scene(32, 32, 1800 + i),
+                                   ("harris",), scene_key=f"early-{i}")
+                      for i in range(4)]
+        deadline = time.time() + 60
+        while fleet.router.outstanding and time.time() < deadline:
+            time.sleep(0.01)
+        assert fleet.router.outstanding == 0
+        tiles = [synthetic_scene(32, 32, 1900 + i) for i in range(10)]
+        with step_lock:                   # all work pending or in flight
+            handles = [fleet.submit(t, ("harris",), scene_key=f"late-{i}")
+                       for i, t in enumerate(tiles)]
+            victim = max(fleet.ready_replicas(),
+                         key=lambda n: fleet.router._slots[n]
+                         .service.scheduler.queue_depth)
+            queued = sum(h.replica == victim for h in handles)
+            assert queued > 0 and fleet.router.outstanding == len(tiles)
+            fleet.kill_replica(victim)
+            assert fleet.router.readmitted == queued
+        deadline = time.time() + 60
+        while fleet.router.outstanding and time.time() < deadline:
+            time.sleep(0.01)
+        assert fleet.router.outstanding == 0
+        for t, h in zip(tiles, handles):
+            assert h.replica != victim
+            assert_results_equal(h.result(60).results, direct(t))
+        assert all(h.done() for h in done_first)
+    finally:
+        fleet.close()
