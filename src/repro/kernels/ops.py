@@ -21,6 +21,7 @@ from repro.kernels import harris as _harris
 from repro.kernels import blur as _blur
 from repro.kernels import fastscore as _fast
 from repro.kernels import matcher as _matcher
+from repro.kernels import patches as _patches
 from repro.kernels import scalespace as _scalespace
 
 LANE = 128
@@ -91,6 +92,22 @@ def fast_score(img, *, threshold: float = 0.15, arc: int = 9,
     out = _fast.fast_pallas(xp, threshold=threshold, arc=arc, h=h, w=wk,
                             interpret=interpret)
     return _crop(out, h, w, squeeze)
+
+
+@functools.partial(jax.jit, static_argnames=("size", "interpret"))
+def patches(img, y0, x0, *, size: int, interpret: bool = None):
+    """Patches [K, size, size] of img [H, W] at start indices y0, x0 [K],
+    already clipped into the tile.  ``interpret=None`` picks by backend:
+    the Pallas kernel on a TPU, where XLA's gather loops over one index or
+    one slice at a time; XLA's window gather anywhere else.  True or False
+    runs the kernel interpreted or compiled."""
+    if interpret is None:
+        if _interpret_default():
+            return jax.vmap(lambda y, x: jax.lax.dynamic_slice(
+                img, (y, x), (size, size)))(y0, x0)
+        interpret = False
+    return _patches.patches_pallas(img, y0, x0, size=size,
+                                   interpret=interpret)
 
 
 def _scalespace_taps(scales_per_octave: int, sigma0: float):

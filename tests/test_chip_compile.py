@@ -75,6 +75,22 @@ def test_scalespace_octave_compiles_at_gated_tile(one_chip):
     assert "tpu_custom_call" in compile_text(fn, tiles(one_chip, 304))
 
 
+@pytest.mark.parametrize("size", [45, 18])
+def test_patch_kernel_compiles_at_job_shape(one_chip, monkeypatch, size):
+    """On a TPU the descriptors' patches come from the Pallas kernel, not
+    XLA's gather (which there serves one index or one slice at a time):
+    the job's 64 tiles a bundle and 512 keypoints, ORB's 45 and SIFT's 18
+    px patches."""
+    from repro.core.descriptors import extract_patches
+    monkeypatch.setattr(ops, "_interpret_default", lambda: False)
+    keys = jax.ShapeDtypeStruct((64, 512), jnp.int32, sharding=one_chip)
+    text = compile_text(jax.vmap(functools.partial(extract_patches,
+                                                   size=size)),
+                        tiles(one_chip, n=64), keys, keys)
+    assert "tpu_custom_call" in text
+    assert " gather(" not in text
+
+
 @pytest.mark.parametrize("metric,path", [
     ("l2", "pallas_resident"), ("l2", "pallas_stream"),
     ("hamming", "pallas_resident"), ("hamming", "pallas_stream")])

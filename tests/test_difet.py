@@ -96,12 +96,10 @@ def test_integral_image_is_batch_invariant():
 
 
 def test_extract_features_fused_equals_seed():
-    """The fused SIFT path and the batched-gather patch extraction must not
-    change extraction results: compare `sift`/`brief`/`orb` against the
-    seed formulations (level-by-level SIFT response; vmapped dynamic_slice
-    patches), field by field."""
-    import jax.numpy as jnp
-    from repro.core import descriptors as DS
+    """The fused SIFT path and the shared FAST response must not change
+    extraction results: compare `sift` against the level-by-level seed
+    response and the multi-algorithm path against per-algorithm
+    extraction, field by field."""
     from repro.core import detectors as D
     from repro.core import engine
 
@@ -139,25 +137,6 @@ def test_extract_features_fused_equals_seed():
         engine.ALGORITHMS["sift"] = orig
     assert_same(r_fused, r_seed, "sift")
 
-    # --- brief/orb: batched-gather patches vs vmapped dynamic_slice -------
-    def patches_seed(img, ys, xs, size):
-        half = size // 2
-
-        def one(y, x):
-            y0 = jnp.clip(y - half, 0, img.shape[0] - size)
-            x0 = jnp.clip(x - half, 0, img.shape[1] - size)
-            return jax.lax.dynamic_slice(img, (y0, x0), (size, size))
-        return jax.vmap(one)(ys, xs)
-
-    img = jnp.asarray(scene)
-    rng = np.random.RandomState(0)
-    ys = jnp.asarray(rng.randint(0, 220, size=32).astype(np.int32))
-    xs = jnp.asarray(rng.randint(0, 220, size=32).astype(np.int32))
-    for size in (18, 31, 45):   # covers sift/brief and orb's rotation margin
-        np.testing.assert_array_equal(
-            np.asarray(DS.extract_patches(img, ys, xs, size)),
-            np.asarray(patches_seed(img, ys, xs, size)), err_msg=str(size))
-
     # --- multi-path (shared FAST response) == per-algorithm extraction ----
     from repro.core.engine import extract_features_multi
     algs = ("sift", "fast", "brief", "orb")
@@ -167,6 +146,76 @@ def test_extract_features_fused_equals_seed():
         single = jax.jit(lambda t, h, a=alg: extract_features(t, h, a, cfg))(
             b.tiles, b.headers)
         assert_same(multi[alg], single, alg)
+
+
+def _patches_numpy(img, ys, xs, size):
+    """Each keypoint's patch by plain slicing, its start clipped into the
+    tile: the reference for ``descriptors.extract_patches``."""
+    h, w = img.shape
+    out = np.empty((len(ys), size, size), img.dtype)
+    for i, (y, x) in enumerate(zip(ys, xs)):
+        y0 = min(max(y - size // 2, 0), h - size)
+        x0 = min(max(x - size // 2, 0), w - size)
+        out[i] = img[y0:y0 + size, x0:x0 + size]
+    return out
+
+
+@pytest.mark.parametrize("n_keys", [512, 128])
+@pytest.mark.parametrize("size", [18, 22, 31, 45])
+def test_extract_patches_equals_numpy_slicing(size, n_keys):
+    """SIFT's 18, SURF's 22, BRIEF's 31 and ORB's 45 px patches at the job's
+    512 and the service's 128 keypoints, a batch of tiles under jit and
+    vmap as the engine calls it: bit-identical to plain slicing, with
+    keypoints on every border and corner so the start clip engages.  The
+    backend's path (XLA's window gather here) and the TPU's Pallas kernel,
+    interpreted, both."""
+    import jax.numpy as jnp
+    from repro.core.descriptors import extract_patches
+    from repro.kernels import ops
+    b, h, w = 3, 70, 300     # neither side a whole number of (8, 128) tiles
+    rng = np.random.RandomState(size * 1000 + n_keys)
+    tiles = rng.rand(b, h, w).astype(np.float32)
+    ys = rng.randint(0, h, (b, n_keys)).astype(np.int32)
+    xs = rng.randint(0, w, (b, n_keys)).astype(np.int32)
+    edge = [(y, x) for y in (0, 1, h // 2, h - 2, h - 1)
+            for x in (0, 1, w // 2, w - 2, w - 1)]
+    ys[:, :len(edge)] = [y for y, _ in edge]
+    xs[:, :len(edge)] = [x for _, x in edge]
+    want = np.stack([_patches_numpy(tiles[i], ys[i], xs[i], size)
+                     for i in range(b)])
+    got = jax.jit(jax.vmap(
+        lambda t, y, x: extract_patches(t, y, x, size)))(tiles, ys, xs)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+    def kernel(t, y, x):
+        half = size // 2
+        return ops.patches(t, jnp.clip(y - half, 0, h - size),
+                           jnp.clip(x - half, 0, w - size), size=size,
+                           interpret=True)
+    got = jax.jit(jax.vmap(kernel))(tiles, ys, xs)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def test_extract_patches_is_one_window_a_keypoint():
+    """At the job's shape (64 tiles, 512 keypoints, ORB's 45 px) the patches
+    must come from a gather of whole windows off a TPU (on one, from the
+    kernel: tests/test_chip_compile.py).  An element gather (slice
+    sizes all 1, one index a pixel) gives the same values but ran at ~23 ns
+    a value on a TPU v5e: 10.4 of the 18.8 s the device was busy in a 20 s
+    window of the paper's Landsat-8 job."""
+    import re
+    import jax.numpy as jnp
+    from repro.core.descriptors import extract_patches
+    text = jax.jit(jax.vmap(lambda t, y, x: extract_patches(t, y, x, 45))).lower(
+        jax.ShapeDtypeStruct((64, 560, 560), jnp.float32),
+        jax.ShapeDtypeStruct((64, 512), jnp.int32),
+        jax.ShapeDtypeStruct((64, 512), jnp.int32)).as_text()
+    sizes = [tuple(int(v) for v in m.split(","))
+             for m in re.findall(r"slice_sizes = array<i64: ([\d, ]+)>", text)]
+    assert sizes, "extract_patches lowered to no gather"
+    assert all(set(s) != {1} for s in sizes), (
+        f"an element gather (slice sizes {sizes}) builds the patches: it ran "
+        "at ~23 ns a value on a TPU v5e; gather one window a keypoint")
 
 
 def test_rgba_conversion_and_bundle_roundtrip(tmp_path):
