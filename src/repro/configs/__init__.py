@@ -1,22 +1,4 @@
-"""Architecture configs.  Importing this package registers every assigned
-architecture (plus the paper's own DIFET pipeline config) in the registry."""
-from repro.configs.base import (  # noqa: F401
-    ModelConfig, MoEConfig, MLAConfig, SSMConfig, XLSTMConfig,
-    ShapeConfig, SHAPES, applicable_shapes, get_config, all_arch_ids,
+"""DIFET's configuration: the paper's pipeline settings (`difet_paper.py`)."""
+from repro.configs.difet_paper import (  # noqa: F401
+    DifetConfig, PAPER_ALGORITHMS, PAPER_CONFIG,
 )
-# architecture modules register themselves on import
-from repro.configs import (  # noqa: F401
-    internlm2_1_8b,
-    qwen1_5_110b,
-    glm4_9b,
-    smollm_135m,
-    whisper_large_v3,
-    deepseek_v3_671b,
-    dbrx_132b,
-    internvl2_2b,
-    xlstm_350m,
-    zamba2_2_7b,
-    difet_paper,
-)
-
-ARCH_IDS = all_arch_ids()

@@ -134,7 +134,7 @@ def sift_dog_response(img, n_octaves=4, scales_per_octave=3,
     per octave, one fused computation (a single Pallas DMA on TPU) yields
     the response and the next octave's seed level — no Gaussian/DoG pyramid
     is materialized.  Matches the level-by-level path
-    (``sift_dog_response_levelwise``, kept for benchmarks) to ~2 ulp with
+    (``sift_dog_response_levelwise``, kept as the tests' oracle) to ~2 ulp with
     identical thresholded detection masks (Table-2 counts unchanged).
     """
     base = blur_separable(img, 1.6, use_pallas)
@@ -152,10 +152,9 @@ def sift_dog_response_levelwise(img, n_octaves=4, scales_per_octave=3,
                                 contrast_threshold=0.04,
                                 use_pallas: bool = False):
     """The seed's level-by-level SIFT path (gaussian_pyramid -> dog_pyramid
-    -> 26-neighbour stack).  Kept as the reference baseline that benchmarks
-    (`benchmarks/run.py::bench_scalespace`) and equivalence tests compare the
-    fused path against; not used by the engine.  Uses the seed blur
-    formulation so the timing baseline is the seed's, not just its math."""
+    -> 26-neighbour stack).  Kept only as the oracle the equivalence tests
+    compare the fused path against; not used by the engine.  Uses the seed
+    blur formulation, so the oracle is the seed's code, not just its math."""
     octs = gaussian_pyramid(img, n_octaves, scales_per_octave,
                             use_pallas=use_pallas,
                             blur_fn=blur_separable_seed)

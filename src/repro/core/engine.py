@@ -26,7 +26,6 @@ from repro.configs.difet_paper import DifetConfig
 from repro.core import detectors as D
 from repro.core import descriptors as DS
 from repro.core import nms
-from repro.distributed.sharding import shard_activation
 
 
 class AlgorithmSpec(NamedTuple):
@@ -267,9 +266,9 @@ def extract_request_features(bundle_tiles, bundle_headers, algorithms,
     are batch-invariant — each row runs the same elementwise program
     regardless of its neighbours or position — so a request's result is
     bit-identical to a direct single-tile ``extract_features_multi`` call no
-    matter which batch the scheduler rode it in (asserted by the
-    ``benchmarks/bench_serve.py`` parity gate and
-    ``tests/test_serve.py::test_served_parity``)."""
+    matter which batch the scheduler rode it in (asserted by
+    ``tests/test_serve.py::test_served_parity`` and
+    ``test_served_parity_per_algorithm``)."""
     algorithms = tuple(algorithms)
     per_tile = jax.vmap(
         functools.partial(extract_tile_multi, algorithms, cfg,

@@ -9,8 +9,8 @@ The SIFT hot path no longer materializes the pyramid level-by-level:
 the next octave's seed level) in one fused computation — on TPU a single
 ``pallas_call`` (`repro.kernels.scalespace`), on CPU a streaming jnp path
 that never builds the 26-neighbour stack.  ``gaussian_pyramid`` /
-``dog_pyramid`` remain as the level-by-level reference substrate
-(benchmarks time fused-vs-levelwise; see DESIGN.md §6).
+``dog_pyramid`` remain as the level-by-level oracle of the equivalence
+tests (see DESIGN.md §6).
 """
 from __future__ import annotations
 
@@ -56,8 +56,7 @@ def blur_separable(img, sigma: float, use_pallas: bool = False):
 def blur_separable_seed(img, sigma: float, use_pallas: bool = False):
     """The seed's blur formulation: pad per pass, convolve along the last
     dim, transpose between passes.  Numerically identical to
-    ``blur_separable``; kept as the level-by-level timing baseline
-    (`benchmarks/run.py::bench_scalespace`) and as the equivalence oracle."""
+    ``blur_separable``; kept only as the equivalence tests' oracle."""
     if use_pallas:
         from repro.kernels.ops import gaussian_blur as _pallas_blur
         return _pallas_blur(img, sigma)
@@ -200,8 +199,9 @@ def gaussian_pyramid(img, n_octaves: int, scales_per_octave: int,
 
     Level-by-level reference path: every level round-trips through HBM.
     The SIFT hot path uses ``fused_octave_response`` instead.  ``blur_fn``
-    lets benchmarks pin the seed blur formulation
-    (``blur_separable_seed``); default is ``blur_separable``.
+    lets the seed oracle (``detectors.sift_dog_response_levelwise``) pin
+    the seed blur formulation (``blur_separable_seed``); default is
+    ``blur_separable``.
     """
     blur_fn = blur_separable if blur_fn is None else blur_fn
     octaves = []

@@ -1,2 +1,1 @@
 from repro.data.landsat import synthetic_scene, synthetic_scene_rgba  # noqa: F401
-from repro.data.tokens import synthetic_lm_batch, token_stream  # noqa: F401

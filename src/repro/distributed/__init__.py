@@ -1,4 +1,3 @@
 from repro.distributed.sharding import (  # noqa: F401
-    use_mesh, current_mesh, shard_activation, param_pspec_tree,
-    make_param_shardings, batch_pspec, dp_axes, data_mesh,
+    batch_pspec, dp_axes, data_mesh,
 )

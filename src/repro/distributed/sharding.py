@@ -1,244 +1,19 @@
-"""Sharding rules: FSDP × TP × EP with divisibility fallback.
-
-Logical axes
-------------
-* ``fsdp``   — parameter shards over the data-parallel axes (ZeRO-3 style):
-               ``("pod", "data")`` on a multi-pod mesh, ``("data",)`` otherwise.
-* ``tensor`` — tensor-parallel over ``model``.
-* ``expert`` — expert-parallel over ``model`` (MoE expert dim).
-
-Rules are name-based (matched against the param path suffix) and produce a
-spec for the *unstacked* param; scan-stacked layer params get the spec
-left-padded with ``None`` for the layer axis.  Any mesh axis that does not
-divide the corresponding dim is dropped (MaxText-style fallback) so ragged
-head counts (smollm 9H, whisper 20H, ...) and vocabs still shard wherever
-divisibility allows.
-"""
+"""Sharding for the DIFET tile path: a 1-D ``data`` mesh over the chips,
+and the one spec the path needs — the tile batch split over the data axes,
+everything per tile kept local."""
 from __future__ import annotations
 
-import contextlib
-import re
-import threading
 from typing import Optional
 
 import jax
 import numpy as np
-from jax import tree_util as jtu
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-_state = threading.local()
-
-
-@contextlib.contextmanager
-def use_mesh(mesh: Mesh):
-    """Set the ambient mesh for sharding constraints (also enters `with mesh`)."""
-    prev = getattr(_state, "mesh", None)
-    _state.mesh = mesh
-    try:
-        with mesh:
-            yield mesh
-    finally:
-        _state.mesh = prev
-
-
-def current_mesh() -> Optional[Mesh]:
-    """The ambient mesh set by `use_mesh`, or None outside any context."""
-    m = getattr(_state, "mesh", None)
-    if m is not None:
-        return m
-    return None
-
-
-@contextlib.contextmanager
-def activation_dp_over_model(flag: bool):
-    """When True, activation batch dims shard over (dp axes + model) —
-    pure-DP activations for archs whose heads can't TP-shard."""
-    prev = getattr(_state, "dp_over_model", False)
-    _state.dp_over_model = flag
-    try:
-        yield
-    finally:
-        _state.dp_over_model = prev
-
-
-def _dp_over_model_active() -> bool:
-    return getattr(_state, "dp_over_model", False)
+from jax.sharding import Mesh, PartitionSpec as P
 
 
 def dp_axes(mesh: Mesh):
     """Data-parallel mesh axes (pod-major on multi-pod meshes)."""
     names = mesh.axis_names
     return tuple(a for a in ("pod", "data") if a in names)
-
-
-# ---------------------------------------------------------------------------
-# logical-axis resolution with divisibility fallback
-# ---------------------------------------------------------------------------
-def _resolve_axis(logical, mesh: Mesh):
-    if logical is None:
-        return None
-    if logical == "fsdp":
-        return dp_axes(mesh)
-    if logical in ("tensor", "expert"):
-        return ("model",) if "model" in mesh.axis_names else ()
-    if logical == "dp":
-        return dp_axes(mesh)
-    raise ValueError(f"unknown logical axis {logical!r}")
-
-
-def resolve_spec(logical_spec, shape, mesh: Mesh) -> P:
-    """logical spec + concrete shape -> PartitionSpec with fallback."""
-    # left-pad for stacked/extra leading dims
-    pad = len(shape) - len(logical_spec)
-    logical_spec = (None,) * pad + tuple(logical_spec)
-    out = []
-    for dim, logical in zip(shape, logical_spec):
-        axes = _resolve_axis(logical, mesh)
-        if not axes:
-            out.append(None)
-            continue
-        kept = []
-        prod = 1
-        for a in axes:
-            asz = mesh.shape[a]
-            if dim % (prod * asz) == 0:
-                kept.append(a)
-                prod *= asz
-        if not kept:
-            out.append(None)
-        elif len(kept) == 1:
-            out.append(kept[0])
-        else:
-            out.append(tuple(kept))
-    return P(*out)
-
-
-# ---------------------------------------------------------------------------
-# parameter rules (ordered; first match on path suffix wins)
-# ---------------------------------------------------------------------------
-PARAM_RULES = [
-    # embeddings / lm head: [V, D]
-    (r"(emb|head|patch_proj)/w$",        ("tensor", "fsdp")),
-    (r"pos_emb$",                        (None, None)),
-    # MoE experts: [E, d, ff] / [E, ff, d]
-    (r"moe/w[iu]$",                      ("expert", "fsdp", None)),
-    (r"moe/wo$",                         ("expert", None, "fsdp")),
-    (r"moe/router$",                     ("fsdp", None)),
-    # attention in-projections: [d, X]
-    (r"(wq|wk|wv|wuq|wdq|wdkv|wkr)$",    ("fsdp", "tensor")),
-    (r"(wuk|wuv)$",                      (None, "tensor")),   # [r, H*hd]
-    # out-projections: [X, d]
-    (r"wo$",                             ("tensor", "fsdp")),
-    # MLP / xlstm / ssm in-projections: [d, F]
-    (r"(wi|wu|in_proj|up_proj)$",        ("fsdp", "tensor")),
-    (r"(out_proj|down_proj)$",           ("tensor", "fsdp")),
-    # biases on tensor-sharded outputs
-    (r"b[qkv]$",                         ("tensor",)),
-    (r"bi$",                             ("tensor",)),
-    (r"(bo|b)$",                         (None,)),
-    # SSM per-channel params: [d_inner] or [H] — shard over tensor
-    (r"(A_log|D|dt_bias)$",              ("tensor",)),
-    (r"conv/w$",                         (None, "tensor")),
-    (r"conv/b$",                         ("tensor",)),
-]
-
-
-def _path_str(path) -> str:
-    parts = []
-    for k in path:
-        if isinstance(k, jtu.DictKey):
-            parts.append(str(k.key))
-        elif isinstance(k, jtu.SequenceKey):
-            parts.append(str(k.idx))
-        else:
-            parts.append(str(k))
-    return "/".join(parts)
-
-
-def pspec_for(path_str: str, shape, mesh: Mesh) -> P:
-    """PartitionSpec for one param: first `PARAM_RULES` suffix match wins,
-    2D+ params fall back to (fsdp, tensor) on the trailing dims, scalars
-    and norm scales replicate.  Sharded dims always divide the mesh axes
-    (`resolve_spec` drops any axis that doesn't)."""
-    for pat, logical in PARAM_RULES:
-        if re.search(pat, path_str):
-            return resolve_spec(logical, shape, mesh)
-    if len(shape) >= 2:
-        # generic 2D+ fallback: fsdp on -2, tensor on -1
-        return resolve_spec(("fsdp", "tensor"), shape, mesh)
-    return P()   # scalars / norm scales replicated
-
-
-def param_pspec_tree(params_shapes, mesh: Mesh):
-    """Map a pytree of ShapeDtypeStruct/arrays -> pytree of PartitionSpec."""
-    def f(path, leaf):
-        return pspec_for(_path_str(path), leaf.shape, mesh)
-    return jtu.tree_map_with_path(f, params_shapes)
-
-
-def make_param_shardings(params_shapes, mesh: Mesh):
-    """`param_pspec_tree` with every spec wrapped in a NamedSharding —
-    the form ``jax.jit(in_shardings=...)`` and device_put consume."""
-    specs = param_pspec_tree(params_shapes, mesh)
-    return jtu.tree_map(lambda s: NamedSharding(mesh, s), specs,
-                        is_leaf=lambda x: isinstance(x, P))
-
-
-# ---------------------------------------------------------------------------
-# activation sharding (used inside model code)
-# ---------------------------------------------------------------------------
-def _act_spec(kind: str, rank: int, mesh: Mesh) -> P:
-    dp = dp_axes(mesh)
-    if _dp_over_model_active() and "model" in mesh.axis_names:
-        dp = dp + ("model",)
-        if kind == "logits":   # vocab can't also use model — pure DP
-            return P(dp, *([None] * (rank - 1)))
-    dp = dp[0] if len(dp) == 1 else dp
-    if kind == "hidden":      # [B, S, D]
-        return P(dp, *([None] * (rank - 1)))
-    if kind == "expert":      # [E, C, D] — EP on E only (C-dim sharding
-        # REFUTED in §Perf iter 4: it forces cross-dp all-reduces on the
-        # expert einsums, +40GiB all-reduce traffic)
-        return P("model", *([None] * (rank - 1)))
-    if kind == "logits":      # [B, S, V]
-        return P(dp, None, "model")
-    if kind == "batch":       # any batch-leading tensor
-        return P(dp, *([None] * (rank - 1)))
-    if kind == "kv_cache":    # [B, S, KVH, hd] — batch-sharded
-        return P(dp, *([None] * (rank - 1)))
-    raise ValueError(kind)
-
-
-def largest_divisible_prefix(dim: int, axes, mesh: Mesh):
-    """Longest prefix of ``axes`` whose size product divides ``dim``."""
-    kept = []
-    prod = 1
-    for a in axes:
-        if dim % (prod * mesh.shape[a]) != 0:
-            break
-        kept.append(a)
-        prod *= mesh.shape[a]
-    if not kept:
-        return None
-    return kept[0] if len(kept) == 1 else tuple(kept)
-
-
-def shard_activation(x, kind: str):
-    """with_sharding_constraint if a mesh is ambient, identity otherwise."""
-    mesh = current_mesh()
-    if mesh is None or mesh.size == 1:
-        return x
-    spec = _act_spec(kind, x.ndim, mesh)
-    # divisibility fallback: keep the largest prefix of grouped axes that
-    # divides (so dp_over_model degrades to plain dp, not to replicated)
-    concrete = []
-    for dim, ax in zip(x.shape, spec):
-        if ax is None:
-            concrete.append(None)
-            continue
-        axes = (ax,) if isinstance(ax, str) else tuple(ax)
-        concrete.append(largest_divisible_prefix(dim, axes, mesh))
-    return jax.lax.with_sharding_constraint(x, P(*concrete))
 
 
 def batch_pspec(mesh: Mesh, rank: int = 2) -> P:

@@ -1,11 +1,10 @@
 """Benchmark-gated matcher dispatch: measure once per shape-bucket, route
 every later call to the winning implementation.
 
-`BENCH_61e2246.json` caught the matcher's jnp "production" formulation at
-a fraction of its oracle's speed on this host — the right formulation is
-a *backend property* (packed chunked scans win on TPU where HBM traffic
-dominates; one fused [Q, K] block wins on CPU XLA; interpret-mode Pallas
-is never a perf path), so hardcoding any single choice loses somewhere.
+The fastest formulation of the matcher is a *backend property* (packed
+chunked scans win on TPU where HBM traffic dominates; one fused [Q, K]
+block wins on CPU XLA; interpret-mode Pallas is never a perf path), so
+hardcoding any single choice loses somewhere.
 Instead, `ops.match_best2` asks :func:`choose_path`, which runs a tiny
 one-shot microbenchmark per ``(metric, backend, shape-bucket)`` the first
 time a bucket is seen, persists the verdict to a small on-disk JSON
@@ -174,9 +173,7 @@ def _probe_arrays(metric: str, nq: int, nk: int, d: int):
 
 def _time_call(fn, *args) -> float:
     """Median-of-reps wall time in us, with a *blocking* warm-up so the
-    first rep never pays compile or the warm-up's async execution (the
-    measurement bug behind the phantom 16x L2 'regression' in
-    BENCH_61e2246)."""
+    first rep never pays compile or the warm-up's async execution."""
     out = fn(*args)
     jax.tree_util.tree_leaves(out)[0].block_until_ready()
     samples = []

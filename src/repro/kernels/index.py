@@ -12,8 +12,8 @@ distances, masking, and smallest-index tie-breaks as the exact paths.
 The only approximation is recall: a query whose true winner fell outside
 the candidate set mismatches.  On matching workloads (near-duplicate
 descriptors at small distance) recall at the default knobs is >0.95 of
-the exact pipeline's accepted matches (`tests/test_index.py`,
-`benchmarks/bench_matcher.py`); the ``probes`` knob trades recall back
+the exact pipeline's accepted matches (`tests/test_index.py`); the
+``probes`` knob trades recall back
 against latency.
 
 * :class:`LshIndex` — ``n_tables`` hash tables, each hashing ``n_bits``

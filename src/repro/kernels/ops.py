@@ -180,8 +180,8 @@ def match_path(nq: int, nk: int, d: int, *, metric: str = "l2",
     database fits the budget, else the streaming tiled-DB kernel — there
     is no silent jnp fallback anymore.  ``use_pallas=False`` restricts to
     the jnp formulations; ``None`` (the default) lets the per-(metric,
-    backend, shape-bucket) microbenchmark decide.  Benchmarks and tests
-    call this to *assert* the dispatch decision (e.g. that a million-row
+    backend, shape-bucket) microbenchmark decide.  Tests call this to
+    *assert* the dispatch decision (e.g. that a million-row
     database streams rather than falling back).
     """
     if use_pallas is True:

@@ -1,6 +1,6 @@
-"""Production mesh construction.
+"""Host mesh construction.
 
-``make_production_mesh`` is a FUNCTION (not a module-level constant) so that
+``make_host_mesh`` is a FUNCTION (not a module-level constant) so that
 importing this module never touches jax device state — device count is
 locked on first jax init, and smoke tests must keep seeing 1 CPU device.
 """
@@ -8,14 +8,6 @@ from __future__ import annotations
 
 import jax
 from jax.sharding import AxisType
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    """16x16 chips per pod ('data' x 'model'); 2 pods stack a 'pod' axis."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes,
-                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():

@@ -55,7 +55,7 @@ def explain_dispatch() -> int:
     print(f"dispatch cache: {dispatch.cache_path()}")
     if not rows:
         print("  (empty — no buckets measured yet; run a matcher "
-              "workload or benchmarks/bench_matcher.py first)")
+              "workload first)")
         return 0
     for key, row in rows.items():
         margin = row.get("margin")

@@ -3,8 +3,8 @@ generator (the online analogue of ``launch/extract.py``'s batch job).
 
 The workload itself — arrival process, hot-scene skew, tile/algorithm
 mix — comes from `serve/trace.py`, the same generator the fleet driver
-(`launch/fleet.py`) and fleet benchmark (`benchmarks/bench_fleet.py`)
-replay, so single-service and fleet numbers describe the same traffic.
+(`launch/fleet.py`) replays, so single-service and fleet numbers describe
+the same traffic.
 
 Closed loop: ``--concurrency`` client threads each submit a request and
 wait for it — models downstream consumers like the stitching pipeline

@@ -10,10 +10,9 @@ Two machine-readable artifacts per observed run:
   ``tid`` is the recording thread, so replica runner threads render as
   separate rows.
 * **Metrics JSON**: the flat :class:`repro/obs/metrics.py::MetricsRegistry`
-  snapshot + the kernel profiler rows — the artifact
-  ``benchmarks/run.py`` folds into ``BENCH_<rev>.json`` so a benchmark
-  row carries the provenance (dispatch decisions, cache hit mix, layer
-  latency quantiles) of the run that produced it.
+  snapshot + the kernel profiler rows, so a run's record carries the
+  provenance (dispatch decisions, cache hit mix, layer latency quantiles)
+  of the run that produced it.
 
 :func:`validate_chrome_trace` is the CI smoke gate's schema check:
 events well-formed, all spans closed (``dur >= 0``), timestamps

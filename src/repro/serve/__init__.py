@@ -10,8 +10,7 @@ router with admission control in ``router.py``, replica pool + lifecycle
 trace generator in ``trace.py``.  Cross-process replicas live in
 ``proc.py`` (worker + parent-side client) over the spooled-file
 transport in ``transport.py``; deterministic fault injection for both
-tests and launch drivers in ``chaos.py``.  The LM-substrate decode
-helpers live in ``serve/lm.py``.
+tests and launch drivers in ``chaos.py``.
 """
 from repro.serve.api import (FeatureService, ServeConfig, ExtractResponse,  # noqa: F401
                              ResponseHandle, ServiceOverloaded, tile_digest,

@@ -25,8 +25,8 @@ the serving subsystem:
       → results are frozen into the cache and the response assembled.
 
 Served results are bit-identical to direct ``extract_features_multi``
-calls on the same padded tile (engine batch-invariance; gated in
-``benchmarks/bench_serve.py``), so caching and batching are pure
+calls on the same padded tile (engine batch-invariance; asserted in
+``tests/test_serve.py``), so caching and batching are pure
 performance — never a numerics fork.
 """
 from __future__ import annotations

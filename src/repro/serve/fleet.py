@@ -50,8 +50,7 @@ shallow for ``scale_down_grace_ticks`` consecutive ticks, and only by
 *draining*: the replica leaves the ring, finishes its queue, retires
 with zero dropped responses.  Every decision is recorded in
 ``Fleet.scale_events`` (trigger metric, value, before/after replica
-count) — `benchmarks/bench_fleet.py` copies them into the
-``BENCH_<rev>.json`` snapshot.
+count).
 """
 from __future__ import annotations
 
@@ -162,8 +161,7 @@ class Fleet:
 
     ``scale_events`` is the audit log of every autoscale decision:
     ``{"action", "trigger", "value", "slo_p99_s", "before", "after"}``
-    dicts in decision order (bounded; benchmarks snapshot it into
-    ``BENCH_<rev>.json``)."""
+    dicts in decision order (bounded)."""
 
     MAX_SCALE_EVENTS = 256
 

@@ -1,6 +1,6 @@
 """Synthetic serving traces: one workload definition shared by the
-single-service load driver (`launch/serve.py`), the fleet driver
-(`launch/fleet.py`) and the fleet benchmark (`benchmarks/bench_fleet.py`).
+single-service load driver (`launch/serve.py`) and the fleet driver
+(`launch/fleet.py`).
 
 A trace is a deterministic list of :class:`TraceEvent` — *when* a request
 arrives (``t`` seconds from trace start), *what* it asks for (scene,

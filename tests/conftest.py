@@ -3,8 +3,8 @@ import os
 import random
 import sys
 
-# Tests must see the real single CPU device (the 512-device override is
-# exclusively dryrun.py's).  Keep compile caches warm across tests.
+# Tests run on the CPU and see its real devices; a test that needs more
+# sets XLA's host device count in a subprocess of its own.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
@@ -59,7 +59,7 @@ def key():
 
 
 class FakeMesh:
-    """Mesh stand-in exposing just what the sharding rules consume
+    """Mesh stand-in exposing just what the sharding helpers consume
     (axis_names / shape / size) without touching device state."""
 
     def __init__(self, **axes):

@@ -1,55 +1,87 @@
-"""Config registry + reduced-config invariants."""
+"""DIFET's configuration: the paper's settings, and what keys on them."""
+import dataclasses
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
 import pytest
 
-from repro.configs import (
-    get_config, all_arch_ids, applicable_shapes, SHAPES)
+from repro.configs import PAPER_ALGORITHMS, PAPER_CONFIG, DifetConfig
+from repro.serve.api import config_digest
 
-EXPECTED_ARCHS = {
-    "internlm2-1.8b", "qwen1.5-110b", "glm4-9b", "smollm-135m",
-    "whisper-large-v3", "deepseek-v3-671b", "dbrx-132b", "internvl2-2b",
-    "xlstm-350m", "zamba2-2.7b",
-}
+SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
-def test_all_assigned_archs_registered():
-    assert EXPECTED_ARCHS == set(all_arch_ids())
+def test_paper_defaults():
+    """Section 4 of the paper: the 7681 x 7831 Landsat-8 scene, SURF's
+    Hessian threshold of 400, FAST-9; tiles of 512 with a 24-px halo."""
+    assert PAPER_CONFIG == DifetConfig()
+    assert PAPER_CONFIG.scene_hw == (7681, 7831)
+    assert PAPER_CONFIG.tile == 512
+    assert PAPER_CONFIG.halo == 24
+    assert PAPER_CONFIG.surf_hessian_threshold == 400.0
+    assert PAPER_CONFIG.fast_arc == 9
 
 
-def test_shapes_table():
-    assert SHAPES["train_4k"].seq_len == 4096
-    assert SHAPES["train_4k"].global_batch == 256
-    assert SHAPES["prefill_32k"].seq_len == 32768
-    assert SHAPES["decode_32k"].is_decode
-    assert SHAPES["long_500k"].seq_len == 524288
+def test_paper_algorithms_are_the_papers_seven():
+    assert PAPER_ALGORITHMS == ("harris", "shi_tomasi", "sift", "surf",
+                                "fast", "brief", "orb")
 
 
-@pytest.mark.parametrize("arch", sorted(EXPECTED_ARCHS))
-def test_applicable_shapes(arch):
-    cfg = get_config(arch)
-    shapes = applicable_shapes(cfg)
-    assert "train_4k" in shapes
-    # long_500k only for sub-quadratic archs (DESIGN.md §4)
-    assert ("long_500k" in shapes) == (cfg.family in ("ssm", "hybrid"))
+def test_config_is_a_jit_static_argument():
+    """Frozen and hashable: the engine jits over it as a static argument,
+    so equal configs share one trace and a changed one traces anew."""
+    assert hash(DifetConfig()) == hash(DifetConfig())
+    with pytest.raises(dataclasses.FrozenInstanceError):
+        PAPER_CONFIG.tile = 256
+    traces = []
+
+    def f(x, cfg):
+        traces.append(cfg)
+        return x * cfg.tile
+
+    g = jax.jit(f, static_argnums=1)
+    x = jnp.ones(3)
+    assert float(g(x, DifetConfig())[0]) == 512.0
+    assert float(g(x, DifetConfig())[0]) == 512.0
+    assert float(g(x, DifetConfig(tile=256))[0]) == 256.0
+    assert len(traces) == 2
 
 
-@pytest.mark.parametrize("arch", sorted(EXPECTED_ARCHS))
-def test_reduced_is_small_and_same_family(arch):
-    cfg = get_config(arch)
-    r = cfg.reduced()
-    assert r.family == cfg.family
-    assert r.d_model <= 128 and r.n_layers <= 4 and r.vocab_size <= 512
-    assert (r.moe is None) == (cfg.moe is None)
-    assert (r.mla is None) == (cfg.mla is None)
-    assert (r.ssm is None) == (cfg.ssm is None)
+def test_the_program_imports_only_difet():
+    """Importing the job, the engine and the service loads DIFET's own
+    packages and nothing else: of the configurations, only the paper's."""
+    code = (
+        "import sys\n"
+        "import repro.core.engine, repro.core.job, repro.serve\n"
+        "print('\\n'.join(sorted(m for m in sys.modules "
+        "if m.startswith('repro.'))))\n")
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, "-c", code], env=env, check=True,
+                         capture_output=True, text=True).stdout.split()
+    assert "repro.core.job" in out and "repro.serve.api" in out
+    packages = {m.split(".")[1] for m in out}
+    assert packages <= {"configs", "core", "data", "distributed", "kernels",
+                        "obs", "serve"}, packages
+    assert [m for m in out if m.startswith("repro.configs.")] == \
+        ["repro.configs.difet_paper"]
 
 
-def test_exact_assigned_dims():
-    q = get_config("qwen1.5-110b")
-    assert (q.n_layers, q.d_model, q.n_heads, q.n_kv_heads, q.d_ff,
-            q.vocab_size) == (80, 8192, 64, 8, 49152, 152064)
-    assert q.qkv_bias
-    d = get_config("deepseek-v3-671b")
-    assert d.moe.n_experts == 256 and d.moe.n_experts_per_tok == 8
-    assert d.mla.kv_lora_rank == 512
-    z = get_config("zamba2-2.7b")
-    assert z.ssm.d_state == 64 and z.n_layers == 54
+@pytest.mark.parametrize("field,value", [
+    ("halo", 24),
+    ("max_keypoints_per_tile", 64),
+    ("fast_threshold", 0.2),
+    ("surf_hessian_threshold", 500.0),
+    ("sift_contrast_threshold", 0.03),
+    ("dtype", "bfloat16"),
+])
+def test_config_digest_keys_every_output_field(field, value):
+    """The service's cache key changes with any field that changes the
+    features, so a changed config is always a cache miss."""
+    base = DifetConfig(tile=256, halo=16, max_keypoints_per_tile=128)
+    changed = dataclasses.replace(base, **{field: value})
+    assert getattr(changed, field) != getattr(base, field)
+    assert config_digest(changed) != config_digest(base)
+    assert config_digest(dataclasses.replace(base)) == config_digest(base)

@@ -196,6 +196,51 @@ def test_extract_patches_equals_numpy_slicing(size, n_keys):
     np.testing.assert_array_equal(np.asarray(got), want)
 
 
+PLACEMENTS = {
+    "top_row": lambda rng, h, w, k: (np.zeros(k), rng.randint(0, w, k)),
+    "left_column": lambda rng, h, w, k: (rng.randint(0, h, k), np.zeros(k)),
+    "bottom_right": lambda rng, h, w, k: (rng.randint(h - 24, h, k),
+                                          rng.randint(w - 24, w, k)),
+    "one_pixel": lambda rng, h, w, k: (np.full(k, rng.randint(h)),
+                                       np.full(k, rng.randint(w))),
+}
+
+
+@pytest.mark.parametrize("placement", list(PLACEMENTS))
+@pytest.mark.parametrize("size", [18, 22, 31, 45])
+def test_extract_patches_at_borders(size, placement):
+    """Every keypoint on the tile's top row, its left column or in its
+    bottom-right corner, or all K of them on one pixel: the start clip
+    engages on one axis, both or none.  Patches of a reflect-padded tile
+    (a scene corner, cut as the job cuts it) equal plain numpy slicing,
+    from XLA's window gather and from the Pallas kernel, interpreted."""
+    import jax.numpy as jnp
+    from repro.core.descriptors import extract_patches
+    from repro.kernels import ops
+    cfg = DifetConfig(tile=128, halo=24, max_keypoints_per_tile=128)
+    bundle = tile_scene(synthetic_scene(150, 200, seed=size), cfg)
+    tiles = bundle.tiles[-2:]          # the scene's bottom row, padded
+    b, h, w = tiles.shape
+    k = cfg.max_keypoints_per_tile
+    rng = np.random.RandomState(size)
+    yx = [PLACEMENTS[placement](rng, h, w, k) for _ in range(b)]
+    ys = np.stack([y for y, _ in yx]).astype(np.int32)
+    xs = np.stack([x for _, x in yx]).astype(np.int32)
+    want = np.stack([_patches_numpy(tiles[i], ys[i], xs[i], size)
+                     for i in range(b)])
+    got = jax.jit(jax.vmap(
+        lambda t, y, x: extract_patches(t, y, x, size)))(tiles, ys, xs)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+    def kernel(t, y, x):
+        half = size // 2
+        return ops.patches(t, jnp.clip(y - half, 0, h - size),
+                           jnp.clip(x - half, 0, w - size), size=size,
+                           interpret=True)
+    got = jax.jit(jax.vmap(kernel))(tiles, ys, xs)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
 def test_extract_patches_is_one_window_a_keypoint():
     """At the job's shape (64 tiles, 512 keypoints, ORB's 45 px) the patches
     must come from a gather of whole windows off a TPU (on one, from the

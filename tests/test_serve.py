@@ -9,7 +9,7 @@ import pytest
 
 import jax
 
-from repro.configs.difet_paper import DifetConfig
+from repro.configs.difet_paper import PAPER_ALGORITHMS, DifetConfig
 from repro.core import engine
 from repro.core.bundle import tile_scene
 from repro.core.job import DifetJob
@@ -172,6 +172,32 @@ def test_served_parity(service):
         assert r.n_tiles == 1 and r.bucket == 32
         assert r.timing["latency_s"] >= 0.0
         assert r.timing["batch_sizes"] and r.timing["batch_sizes"][0] >= 1
+
+
+@pytest.fixture(scope="module")
+def service256():
+    """The tile service as the benchmark's configuration runs it: bucket
+    256, halo 16, 128 keypoints, batches of up to 8."""
+    svc = FeatureService(ServeConfig(
+        base=DifetConfig(tile=256, halo=16, max_keypoints_per_tile=128),
+        buckets=(256,), max_batch=8, max_batch_delay_s=0.005))
+    yield svc
+    svc.close()
+
+
+@pytest.mark.parametrize("alg", PAPER_ALGORITHMS)
+def test_served_parity_per_algorithm(service256, alg):
+    """Each algorithm served at the 256 bucket is bit-identical to a
+    direct engine call on the same padded tile, for full tiles and a
+    smaller one padded into the bucket, whatever batch they rode in."""
+    tiles = [synthetic_scene(256, 256, seed=s, density=4.0)
+             for s in range(3)] + [synthetic_scene(200, 230, seed=3)]
+    handles = [service256.submit(t, (alg,)) for t in tiles]
+    for t, h in zip(tiles, handles):
+        r = h.result(120)
+        assert r.bucket == 256
+        assert_results_equal(_direct(service256.table, t, (alg,)), r.results)
+        assert r.results[alg]["top_valid"].any()
 
 
 def test_repeat_requests_served_from_cache(service):

@@ -1,28 +1,9 @@
-"""Sharding rules: divisibility fallback, coverage over every arch's params."""
+"""Sharding of the tile path: the data mesh and the batch spec."""
 import jax
-import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.configs import get_config, all_arch_ids
-from repro.distributed.sharding import (
-    resolve_spec, pspec_for, param_pspec_tree, dp_axes)
-from repro.models import build_model
-
-
-def shapes_tree(arch):
-    model = build_model(get_config(arch))
-    return jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
-
-
-def test_resolve_spec_divisibility_fallback(mesh_16x16):
-    # 9 heads * 64 = 576 divisible by 16 -> shards; 9 alone does not
-    assert resolve_spec(("fsdp", "tensor"), (576, 576), mesh_16x16) \
-        == P("data", "model")
-    assert resolve_spec((None, "tensor"), (4, 9), mesh_16x16) == P(None, None)
-    # left-padding for stacked params
-    assert resolve_spec(("fsdp", "tensor"), (24, 576, 1536), mesh_16x16) \
-        == P(None, "data", "model")
+from repro.distributed.sharding import batch_pspec, data_mesh, dp_axes
 
 
 def test_dp_axes(mesh_16x16, mesh_pod):
@@ -30,48 +11,33 @@ def test_dp_axes(mesh_16x16, mesh_pod):
     assert dp_axes(mesh_pod) == ("pod", "data")
 
 
-def test_moe_expert_rule(mesh_16x16):
-    spec = pspec_for("stack/moe/wi", (58, 256, 7168, 2048), mesh_16x16)
-    assert spec == P(None, "model", "data", None)
-    spec = pspec_for("stack/moe/wo", (58, 256, 2048, 7168), mesh_16x16)
-    assert spec == P(None, "model", None, "data")
+def test_data_mesh_one_device():
+    mesh = data_mesh(1)
+    assert mesh.axis_names == ("data",)
+    assert mesh.devices.shape == (1,)
+    assert list(mesh.devices.flat) == jax.devices()[:1]
 
 
-@pytest.mark.parametrize("arch", ["smollm-135m", "whisper-large-v3",
-                                  "deepseek-v3-671b", "xlstm-350m",
-                                  "zamba2-2.7b"])
-def test_rules_valid_for_every_param(arch, mesh_16x16, mesh_pod):
-    """Every param gets a spec whose sharded dims are divisible — the
-    invariant that makes .lower() succeed for every arch."""
-    tree = shapes_tree(arch)
-    for mesh in (mesh_16x16, mesh_pod):
-        specs = param_pspec_tree(tree, mesh)
-        leaves_s = jax.tree_util.tree_leaves(
-            specs, is_leaf=lambda x: isinstance(x, P))
-        leaves_t = jax.tree_util.tree_leaves(tree)
-        assert len(leaves_s) == len(leaves_t)
-        for spec, leaf in zip(leaves_s, leaves_t):
-            for dim, ax in zip(leaf.shape, tuple(spec)):
-                if ax is None:
-                    continue
-                axes = (ax,) if isinstance(ax, str) else ax
-                prod = int(np.prod([mesh.shape[a] for a in axes]))
-                assert dim % prod == 0, (arch, spec, leaf.shape)
+def test_data_mesh_defaults_to_every_device():
+    mesh = data_mesh(None)
+    assert mesh.axis_names == ("data",)
+    assert list(mesh.devices.flat) == jax.devices()
 
 
-def test_params_mostly_sharded_for_large_arch(mesh_16x16):
-    """FSDP must actually shard the big weights (ZeRO sanity)."""
-    tree = shapes_tree("qwen1.5-110b")
-    specs = param_pspec_tree(tree, mesh_16x16)
-    big_total, big_sharded = 0, 0
-    for spec, leaf in zip(
-            jax.tree_util.tree_leaves(specs,
-                                      is_leaf=lambda x: isinstance(x, P)),
-            jax.tree_util.tree_leaves(tree)):
-        n = int(np.prod(leaf.shape))
-        if n < 1e6:
-            continue
-        big_total += n
-        if any(ax is not None for ax in tuple(spec)):
-            big_sharded += n
-    assert big_sharded / big_total > 0.999
+@pytest.mark.parametrize("n", [0, len(jax.devices()) + 1],
+                         ids=["zero", "above_device_count"])
+def test_data_mesh_refuses_out_of_range(n):
+    with pytest.raises(ValueError, match="outside"):
+        data_mesh(n)
+
+
+@pytest.mark.parametrize("rank", [2, 3, 4])
+def test_batch_pspec_splits_only_the_batch(rank, mesh_16x16):
+    """Tiles [N, H, W], headers [N, 6]: only N is split, over ``data``."""
+    assert batch_pspec(mesh_16x16, rank) == P("data", *([None] * (rank - 1)))
+
+
+def test_batch_pspec_on_pod_mesh(mesh_pod):
+    """A multi-pod mesh splits the batch over both data axes, pod first,
+    and never over ``model``."""
+    assert batch_pspec(mesh_pod, 3) == P(("pod", "data"), None, None)

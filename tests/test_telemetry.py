@@ -1,7 +1,7 @@
 """Fleet telemetry plane: cross-process metrics shipping
 (`repro/obs/ship.py`), parent-side aggregation (`repro/obs/agg.py`),
 SLO burn-rate monitoring (`repro/obs/slo.py`), the Prometheus exporter,
-the torn-snapshot transport fix, and the perf-regression sentry.
+and the torn-snapshot transport fix.
 
 The histogram-mergeability property — K workers' shipped bucket deltas
 merged parent-side are *indistinguishable* from one histogram that
@@ -13,9 +13,7 @@ import json
 import math
 import os
 import random
-import sys
 import time
-from pathlib import Path
 
 import numpy as np
 import pytest
@@ -31,8 +29,6 @@ from repro.obs.slo import BurnRateMonitor, SloPolicy
 from repro.obs.trace import FlightRecorder, Span
 from repro.serve.transport import WorkerMailbox, read_message, read_snapshot
 
-sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "benchmarks"))
-from regress import diff_snapshots  # noqa: E402
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -378,30 +374,6 @@ def test_burn_rate_counts_sheds_as_bad_events(tmp_path):
     r = mon.tick()
     assert r["alerting"]
     assert r["burn_fast"] == pytest.approx((90 / 100) / 0.1)
-
-
-# ---- perf-regression sentry -------------------------------------------------
-
-def _snap(rev, rows):
-    return {"rev": rev, "quick": True,
-            "rows": [{"name": n, "us_per_call": us, "derived": ""}
-                     for n, us in rows]}
-
-
-def test_diff_snapshots_statuses():
-    old = _snap("aaa", [("k/a", 100.0), ("k/b", 100.0), ("k/c", 100.0),
-                        ("k/gone", 50.0), ("k/err", 0.0)])
-    new = _snap("bbb", [("k/a", 110.0), ("k/b", 140.0), ("k/c", 200.0),
-                        ("k/new", 10.0), ("k/err", 0.0)])
-    res = {r["name"]: r for r in
-           diff_snapshots(old, new, warn=1.25, fail=1.5)}
-    assert res["k/a"]["status"] == "ok"
-    assert res["k/b"]["status"] == "warn"
-    assert res["k/c"]["status"] == "fail"
-    assert res["k/c"]["ratio"] == pytest.approx(2.0)
-    assert res["k/new"]["status"] == "added"
-    assert res["k/gone"]["status"] == "removed"
-    assert "k/err" not in res                  # zero-timed rows skipped
 
 
 # ---- cross-process trace stitch (proc fleet, telemetry on) ------------------
