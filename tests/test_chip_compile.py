@@ -91,6 +91,67 @@ def test_patch_kernel_compiles_at_job_shape(one_chip, monkeypatch, size):
     assert " gather(" not in text
 
 
+def test_orb_compiles_without_a_gather_at_job_shape(one_chip, monkeypatch):
+    """ORB at the job's shape on a TPU: the patches from the Pallas kernel
+    and the rotated pairs from a one-hot contraction, so no gather is
+    left in the program."""
+    from repro.core.descriptors import orb_descriptors
+    monkeypatch.setattr(ops, "_interpret_default", lambda: False)
+    keys = jax.ShapeDtypeStruct((64, 512), jnp.int32, sharding=one_chip)
+    text = compile_text(jax.vmap(orb_descriptors), tiles(one_chip, n=64),
+                        keys, keys)
+    assert "tpu_custom_call" in text
+    assert " gather(" not in text
+
+
+@pytest.fixture(scope="module")
+def attached_tpu():
+    if jax.default_backend() != "tpu":
+        pytest.skip("runs on a TPU only (JAX_PLATFORMS=tpu on a TPU host)")
+
+
+def test_orb_pair_selection_is_exact_on_the_chip(attached_tpu, monkeypatch):
+    """On a TPU the one-hot contraction runs on the MXU at HIGHEST
+    precision, which splits each f32 value into three bf16 parts; against
+    a 1.0 they sum back to the value exactly.  At the job's shape (64
+    tiles of 560 px, 512 keypoints, on every border and corner too): the
+    selected values of full-mantissa patches of mixed sign and scale, and
+    the descriptors, bit for bit the element gather's."""
+    from repro.core import descriptors as D
+    from repro.data.landsat import synthetic_scene
+    rng = np.random.RandomState(0)
+    p = rng.randn(64 * 512, 45, 45) * 10.0 ** rng.uniform(-6, 6, (64 * 512, 45, 45))
+    p = p.astype(np.float32)
+    ry = rng.randint(0, 45, (64 * 512, 256)).astype(np.int32)
+    rx = rng.randint(0, 45, (64 * 512, 256)).astype(np.int32)
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(D._select_points)(p, ry, rx)),
+        np.asarray(jax.jit(_take_points)(p, ry, rx)))
+
+    n, k = 64, 512
+    scene = synthetic_scene(8 * TILE, 8 * TILE, seed=1)
+    tl = scene.reshape(8, TILE, 8, TILE).transpose(0, 2, 1, 3).reshape(
+        n, TILE, TILE)
+    ys = rng.randint(0, TILE, (n, k)).astype(np.int32)
+    xs = rng.randint(0, TILE, (n, k)).astype(np.int32)
+    edge = [(y, x) for y in (0, 1, TILE // 2, TILE - 2, TILE - 1)
+            for x in (0, 1, TILE // 2, TILE - 2, TILE - 1)]
+    ys[:, :len(edge)] = [y for y, _ in edge]
+    xs[:, :len(edge)] = [x for _, x in edge]
+    got = np.asarray(jax.jit(jax.vmap(D.orb_descriptors))(tl, ys, xs))
+    monkeypatch.setattr(D, "_select_points", _take_points)
+    want = np.asarray(jax.jit(jax.vmap(D.orb_descriptors))(tl, ys, xs))
+    np.testing.assert_array_equal(got, want)
+
+
+def _take_points(patches, ys, xs):
+    """ORB's pair lookup as an element gather: the one-hot selection's
+    oracle."""
+    p = patches.shape[-1]
+    return jnp.take_along_axis(patches.reshape(patches.shape[0], -1),
+                               ys * p + xs, axis=1)
+
+
 @pytest.mark.parametrize("metric,path", [
     ("l2", "pallas_resident"), ("l2", "pallas_stream"),
     ("hamming", "pallas_resident"), ("hamming", "pallas_stream")])

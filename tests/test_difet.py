@@ -263,6 +263,75 @@ def test_extract_patches_is_one_window_a_keypoint():
         "at ~23 ns a value on a TPU v5e; gather one window a keypoint")
 
 
+def _take_points(patches, ys, xs):
+    """ORB's pair lookup as an element gather, one index a point: the form
+    the one-hot selection replaced, kept as its oracle."""
+    import jax.numpy as jnp
+    p = patches.shape[-1]
+    return jnp.take_along_axis(patches.reshape(patches.shape[0], -1),
+                               ys * p + xs, axis=1)
+
+
+@pytest.mark.parametrize("n_keys", [512, 128])
+def test_orb_pair_selection_equals_element_gather(n_keys, monkeypatch):
+    """ORB's rotated pair values come from a one-hot contraction: bit for
+    bit the element gather's, at the job's 512 and the service's 128
+    keypoints, a batch of tiles under jit and vmap as the engine calls it.
+    Keypoints sit on every border and corner; one patch is flat, so every
+    pair test ties and gives 0; the orientations cover all 30 bins."""
+    from repro.core import descriptors as D
+    from repro.core.pyramid import blur_separable
+    b, h, w = 4, 96, 200
+    rng = np.random.RandomState(n_keys)
+    tiles = np.stack([synthetic_scene(h, w, seed=s) for s in range(b)])
+    tiles[0, :64, :64] = 0.5
+    ys = rng.randint(0, h, (b, n_keys)).astype(np.int32)
+    xs = rng.randint(0, w, (b, n_keys)).astype(np.int32)
+    edge = [(y, x) for y in (0, 1, h // 2, h - 2, h - 1)
+            for x in (0, 1, w // 2, w - 2, w - 1)] + [(32, 32)]
+    ys[:, :len(edge)] = [y for y, _ in edge]
+    xs[:, :len(edge)] = [x for _, x in edge]
+    got = np.asarray(jax.jit(jax.vmap(D.orb_descriptors))(tiles, ys, xs))
+
+    def patches(t, y, x):
+        return D.extract_patches(blur_separable(t, 2.0), y, x, 45)
+    pp = jax.jit(jax.vmap(patches))(tiles, ys, xs)
+    theta = np.asarray(jax.vmap(D.orb_orientation)(pp[:, :, 7:38, 7:38]))
+    bins = np.round(theta / (2 * np.pi / 30)).astype(np.int64) % 30
+    assert len(np.unique(bins)) == 30
+    ry = rng.randint(0, 45, (b, n_keys, 256)).astype(np.int32)
+    rx = rng.randint(0, 45, (b, n_keys, 256)).astype(np.int32)
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(jax.vmap(D._select_points))(pp, ry, rx)),
+        np.asarray(jax.vmap(_take_points)(pp, ry, rx)))
+
+    monkeypatch.setattr(D, "_select_points", _take_points)
+    want = np.asarray(jax.jit(jax.vmap(D.orb_descriptors))(tiles, ys, xs))
+    np.testing.assert_array_equal(got, want)
+    assert (got[0, len(edge) - 1] == 0).all()          # the flat patch
+    assert np.count_nonzero(got) > got.size // 2
+
+
+def test_orb_pair_lookup_is_no_element_gather():
+    """At the job's shape (64 tiles of 560 px, 512 keypoints) ORB's only
+    gather is the patch window (slice sizes 1, 45, 45): its 256 rotated
+    pairs are a one-hot contraction.  The element gather they were (two
+    ``take_along_axis``, one value at a time at ~14.5 ns a value on a TPU
+    v5e) held 46% of the Landsat-8 job's device time."""
+    import re
+    import jax.numpy as jnp
+    from repro.core.descriptors import orb_descriptors
+    text = jax.jit(jax.vmap(orb_descriptors)).lower(
+        jax.ShapeDtypeStruct((64, 560, 560), jnp.float32),
+        jax.ShapeDtypeStruct((64, 512), jnp.int32),
+        jax.ShapeDtypeStruct((64, 512), jnp.int32)).as_text()
+    sizes = [tuple(int(v) for v in m.split(","))
+             for m in re.findall(r"slice_sizes = array<i64: ([\d, ]+)>", text)]
+    assert sizes == [(1, 45, 45)], (
+        f"ORB lowers to gathers of slice sizes {sizes}: only the patch "
+        "window may be one; select the pairs by one-hot contraction")
+
+
 def test_rgba_conversion_and_bundle_roundtrip(tmp_path):
     rgba = synthetic_scene_rgba(120, 140, seed=0)
     gray = rgba_to_gray(rgba)
